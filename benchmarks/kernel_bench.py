@@ -59,7 +59,8 @@ def _bench_ssd():
     B = jax.random.normal(jax.random.PRNGKey(6), (b, s, nn)) * 0.3
     C = jax.random.normal(jax.random.PRNGKey(7), (b, s, nn)) * 0.3
     us_ref = _time(lambda *a: ssd_ref_chunked(*a, chunk=c), x, dt, A, B, C)
-    us_ker = _time(lambda *a: ssd_scan(*a, chunk=c), x, dt, A, B, C)
+    us_ker = _time(lambda *a: ssd_scan(*a, chunk=c, interpret=True),
+                   x, dt, A, B, C)
     _emit("kernel.ssd_scan.ref_cpu", us_ref, "pure-jnp chunked")
     _emit("kernel.ssd_scan.interp_cpu", us_ker,
           "pallas interpreter (correctness mode, not perf)")
@@ -105,9 +106,10 @@ def _bench_hybrid_attention():
         pt, pty, pn = _hybrid_tables(kind, B, MAXP, used, rng)
         args = (q, ks, vs, ap, sc, wk, wv, pt, pty, pn)
         us_full = _time(lambda *a: hybrid_paged_attention(
-            *a, norm_type="layernorm"), *args, reps=2)
+            *a, norm_type="layernorm", interpret=True), *args, reps=2)
         us_bound = _time(lambda *a: hybrid_paged_attention(
-            *a, norm_type="layernorm", pages_bound=used), *args, reps=2)
+            *a, norm_type="layernorm", pages_bound=used, interpret=True),
+            *args, reps=2)
         us_ref = _time(lambda *a: hybrid_paged_attention_ref(
             *a, norm_type="layernorm"), *args, reps=2)
         # analytic TPU estimate: QK^T+PV over used pages + one Eq.7
@@ -156,8 +158,10 @@ def _bench_sharded_hybrid_attention():
     pt, pty, pn = jnp.asarray(pt), jnp.asarray(pty), jnp.asarray(pn)
     args = (q, ks, vs, ap, sc, wk, wv, pt, pty, pn)
 
-    kern = lambda *a: hybrid_paged_attention(*a, norm_type="layernorm")
-    mesh = jax.make_mesh((2,), ("model",))
+    kern = lambda *a: hybrid_paged_attention(*a, norm_type="layernorm",
+                                             interpret=True)
+    mesh = jax.make_mesh((2,), ("model",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     rep = P(None)
     f_sharded = shard_map(
         kern, mesh=mesh,

@@ -2,7 +2,8 @@
 
 The offload executor needs to know how much *device* memory it may treat as
 resident: weight double buffers plus however many KV blocks fit.  On the
-real target the budget is the accelerator's HBM; on the reduced CPU configs
+real target the budget is the memory the accelerator reports, less the
+weights that stay resident; on the reduced CPU configs
 the budget is deliberately TIGHT so the runtime exercises real spill — KV
 regions physically living in the pinned host arena between decode steps —
 instead of quietly keeping everything device-resident at smoke scale.
@@ -14,7 +15,7 @@ from typing import Dict
 
 from repro.configs.base import ModelConfig
 from repro.core.blocks import kv_block_bytes
-from repro.core.costmodel import layer_weight_bytes
+from repro.core.costmodel import HardwareSpec, layer_weight_bytes
 
 
 @dataclass(frozen=True)
@@ -42,12 +43,26 @@ def _tight(cfg: ModelConfig, kv_blocks: int = 2,
 BUDGETS: Dict[str, OffloadBudget] = {}
 
 
-def offload_budget(cfg: ModelConfig) -> OffloadBudget:
+def resident_weight_bytes(cfg: ModelConfig) -> int:
+    """Bytes of the weights the offload runtime keeps on the device: the
+    (vocab-padded) embedding, an untied unembedding, the learned position
+    table and the final norm — everything but the streamed layers."""
+    from repro.models.transformer import pad_vocab
+    table = pad_vocab(cfg.vocab_size) * cfg.d_model
+    n = table * (1 if cfg.tie_embeddings else 2) + 2 * cfg.d_model
+    if cfg.pos_type == "learned":
+        n += cfg.max_seq_len * cfg.d_model
+    return n * cfg.bytes_per_param()
+
+
+def offload_budget(cfg: ModelConfig, hw: HardwareSpec) -> OffloadBudget:
     """Budget for a config: explicit entry if registered, else reduced
     (smoke) configs get the spill-forcing tight budget and full-size configs
-    get a 16 GiB device-class budget."""
+    get the device memory ``hw`` reports (``costmodel.hardware_for`` reads
+    the chip's allocator limit) less the resident weights."""
     if cfg.name in BUDGETS:
         return BUDGETS[cfg.name]
     if cfg.name.endswith("-reduced"):
         return _tight(cfg)
-    return OffloadBudget(dev_bytes=16 * 2**30)
+    return OffloadBudget(
+        dev_bytes=max(int(hw.device_mem) - resident_weight_bytes(cfg), 0))

@@ -81,7 +81,37 @@ TPU_V5E = HardwareSpec(
     mfu=0.5,
 )
 
-HARDWARE = {h.name: h for h in (RTX4090, TPU_V5E)}
+#: specs of devices this process can run on, keyed by JAX's ``device_kind``
+#: (peaks: Google Cloud documentation, "TPU v5e")
+HARDWARE = {"TPU v5 lite": TPU_V5E}
+
+
+def hardware_for(device) -> HardwareSpec:
+    """The spec of a live accelerator: looked up by ``device.device_kind``,
+    with ``device_mem`` replaced by the allocator limit the device reports
+    (``memory_stats()["bytes_limit"]``).  A kind that is not in ``HARDWARE``
+    raises — it never inherits another chip's peaks."""
+    kind = device.device_kind
+    if kind not in HARDWARE:
+        raise KeyError(f"no HardwareSpec for device_kind {kind!r}; add its "
+                       f"published peaks to costmodel.HARDWARE")
+    stats = device.memory_stats() or {}
+    if "bytes_limit" not in stats:
+        return HARDWARE[kind]
+    return dataclasses.replace(HARDWARE[kind],
+                               device_mem=float(stats["bytes_limit"]))
+
+
+def local_hardware() -> HardwareSpec:
+    """The spec the servers plan with when none is given: the TPU this
+    process runs on (``hardware_for``), or — on any other backend, where
+    the CPU tests and the simulator run — ``TPU_V5E`` as the prior the
+    simulator predicts for."""
+    import jax
+    device = jax.devices()[0]
+    if device.platform != "tpu":
+        return TPU_V5E
+    return hardware_for(device)
 
 
 def scale_for_shards(hw: HardwareSpec, shards: int) -> HardwareSpec:

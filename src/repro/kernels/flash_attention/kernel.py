@@ -51,9 +51,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
 
     @pl.when(live)
     def _attend():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * sm_scale    # (qc, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)               # (kc, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0].astype(jnp.float32) * sm_scale             # (qc, D)
+        k = k_ref[0].astype(jnp.float32)                        # (kc, D)
+        v = v_ref[0].astype(jnp.float32)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)  # (qc, kc)
         qpos = qi * q_chunk + lax.broadcasted_iota(jnp.int32, s.shape, 0)
         kpos = kj * k_chunk + lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -75,16 +75,20 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s, *,
 
     @pl.when(kj == n_k - 1)
     def _finalize():
-        o_ref[0, :, 0, :] = (acc[...] /
-                             jnp.maximum(l_s[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0] = (acc[...] /
+                    jnp.maximum(l_s[...], 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "q_chunk",
                                              "k_chunk", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     q_chunk: int = 512, k_chunk: int = 512,
-                    interpret: bool = True):
-    """q (B,S,H,D); k,v (B,S,KVH,D) -> (B,S,H,D).  S % chunk == 0 (caller pads)."""
+                    interpret: bool = False):
+    """q (B,S,H,D); k,v (B,S,KVH,D) -> (B,S,H,D).  S % chunk == 0 (caller pads).
+
+    Heads ride the lane axis — (B, S, H*D) views, head h is the h-th D-wide
+    lane block — because the TPU refuses a width-1 block over the head axis.
+    """
     B, S, H, D = q.shape
     KVH = k.shape[2]
     G = H // KVH
@@ -100,17 +104,18 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                           q_chunk=q_chunk, k_chunk=k_chunk, sm_scale=sm_scale),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, q_chunk, 1, D), lambda b, h, qi, kj: (b, qi, h, 0)),
-            pl.BlockSpec((1, k_chunk, 1, D), lambda b, h, qi, kj: (b, kj, h // G, 0)),
-            pl.BlockSpec((1, k_chunk, 1, D), lambda b, h, qi, kj: (b, kj, h // G, 0)),
+            pl.BlockSpec((1, q_chunk, D), lambda b, h, qi, kj: (b, qi, h)),
+            pl.BlockSpec((1, k_chunk, D), lambda b, h, qi, kj: (b, kj, h // G)),
+            pl.BlockSpec((1, k_chunk, D), lambda b, h, qi, kj: (b, kj, h // G)),
         ],
-        out_specs=pl.BlockSpec((1, q_chunk, 1, D), lambda b, h, qi, kj: (b, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, S, H, D), q.dtype),
+        out_specs=pl.BlockSpec((1, q_chunk, D), lambda b, h, qi, kj: (b, qi, h)),
+        out_shape=jax.ShapeDtypeStruct((B, S, H * D), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((q_chunk, D), jnp.float32),
             pltpu.VMEM((q_chunk, 1), jnp.float32),
             pltpu.VMEM((q_chunk, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v)
-    return out
+    )(q.reshape(B, S, H * D), k.reshape(B, S, KVH * D),
+      v.reshape(B, S, KVH * D))
+    return out.reshape(B, S, H, D)

@@ -6,7 +6,7 @@ from repro.kernels.flash_attention.ref import flash_attention_ref
 
 
 def attention(q, k, v, *, causal=True, window=0, use_kernel=True,
-              interpret=True, q_chunk=512, k_chunk=512):
+              interpret=False, q_chunk=512, k_chunk=512):
     if use_kernel:
         return flash_attention(q, k, v, causal=causal, window=window,
                                q_chunk=q_chunk, k_chunk=k_chunk,

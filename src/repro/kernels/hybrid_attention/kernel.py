@@ -60,6 +60,14 @@ PAGE = 16
 NEG_INF = -1e30
 
 
+def _head_column(scales, h):
+    """Column ``h`` of a (T, KVH) scale tile as (T, 1) float32 (a lane
+    select: a width-1 block over the head axis is not a legal TPU tile)."""
+    s = scales.astype(jnp.float32)
+    col = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.sum(jnp.where(col == h, s, 0.0), axis=1, keepdims=True)
+
+
 def _hybrid_attn_kernel(
         # scalar prefetch
         page_table, page_type, page_ntok, n_used,
@@ -116,17 +124,18 @@ def _hybrid_attn_kernel(
         q = q_ref[0, 0].astype(jnp.float32) * sm_scale      # (G, D)
 
         def kv_path():
-            k = k_ref[0, :, 0, :].astype(jnp.float32)        # (T, D)
-            v = v_ref[0, :, 0, :].astype(jnp.float32)
+            k = k_ref[0].astype(jnp.float32)                 # (T, D)
+            v = v_ref[0].astype(jnp.float32)
             if quantized:
-                # per-(token, head) scales, (T, 1): dequant on the VMEM tile
-                k = k * ks_ref[0, :, 0, :].astype(jnp.float32)
-                v = v * vs_ref[0, :, 0, :].astype(jnp.float32)
+                # per-(token, head) scales: the block holds every head's
+                # (T, KVH) column; dequant on the VMEM tile with head h's
+                k = k * _head_column(ks_ref[0], h)
+                v = v * _head_column(vs_ref[0], h)
             return k, v
 
         def act_path():
-            wk = wk_ref[:, 0, :].astype(jnp.float32)         # (d_model, D)
-            wv = wv_ref[:, 0, :].astype(jnp.float32)
+            wk = wk_ref[...].astype(jnp.float32)             # (d_model, D)
+            wv = wv_ref[...].astype(jnp.float32)
             a = a_norm[...]
             return (jnp.dot(a, wk, preferred_element_type=jnp.float32),
                     jnp.dot(a, wv, preferred_element_type=jnp.float32))
@@ -167,7 +176,7 @@ def hybrid_paged_attention(q, k_pages, v_pages, act_pages, norm_scale, wk, wv,
                            k_scales=None, v_scales=None, act_scales=None,
                            norm_type: str = "layernorm", eps: float = 1e-5,
                            pages_bound: int | None = None,
-                           interpret: bool = True,
+                           interpret: bool = False,
                            return_lse: bool = False):
     """-> (B, KVH, G, D) attention output over the hybrid paged cache.
 
@@ -217,13 +226,17 @@ def hybrid_paged_attention(q, k_pages, v_pages, act_pages, norm_scale, wk, wv,
         # iteration and re-issue the page-0 DMA KVH times per dead page
         live = p < nu[b]
         return (jnp.where(live & (pty[b, p] == 0), pt[b, p], 0), 0,
-                jnp.where(live, h, 0), 0)
+                jnp.where(live, h, 0))
+
+    def ks_index(b, p, h, pt, pty, pn, nu):
+        # scale tiles carry every head: only the page coordinate moves
+        return (jnp.where((p < nu[b]) & (pty[b, p] == 0), pt[b, p], 0), 0, 0)
 
     def act_index(b, p, h, pt, pty, pn, nu):
         return (jnp.where((p < nu[b]) & (pty[b, p] == 1), pt[b, p], 0), 0, 0)
 
     def w_index(b, p, h, pt, pty, pn, nu):
-        return (0, jnp.where(p < nu[b], h, 0), 0)
+        return (0, jnp.where(p < nu[b], h, 0))
 
     def q_index(b, p, h, pt, pty, pn, nu):
         return (b, jnp.where(p < nu[b], h, 0), 0, 0)
@@ -235,26 +248,36 @@ def hybrid_paged_attention(q, k_pages, v_pages, act_pages, norm_scale, wk, wv,
         # are always overwritten by that block's later finalize flush.
         return (b, jnp.where((p < nu[b]) | (p == PB - 1), h, 0), 0, 0)
 
+    # the TPU tiles a block's last two dims by (8, 128) unless a dim is
+    # whole, so a width-1 block over the head axis is refused.  Heads are
+    # folded into the lane axis instead — (P, T, KVH*D), (d_model, KVH*D),
+    # free row-major reshapes — and head h is the h-th D-wide lane block.
     in_specs = [
         pl.BlockSpec((1, 1, G, D), q_index),
-        pl.BlockSpec((1, T, 1, D), k_index),
-        pl.BlockSpec((1, T, 1, D), k_index),
+        pl.BlockSpec((1, T, D), k_index),
+        pl.BlockSpec((1, T, D), k_index),
         pl.BlockSpec((1, T, d_model), act_index),
         pl.BlockSpec((1, d_model), lambda b, p, h, pt, pty, pn, nu: (0, 0)),
-        pl.BlockSpec((d_model, 1, D), w_index),
-        pl.BlockSpec((d_model, 1, D), w_index),
+        pl.BlockSpec((d_model, D), w_index),
+        pl.BlockSpec((d_model, D), w_index),
     ]
-    operands = [q, k_pages, v_pages, act_pages, scale2d, wk, wv]
+    operands = [q, k_pages.reshape(P_kv, T, KVH * D),
+                v_pages.reshape(P_kv, T, KVH * D), act_pages, scale2d,
+                wk.reshape(d_model, KVH * D), wv.reshape(d_model, KVH * D)]
     if quantized:
-        # scale sidecars reuse the payload index maps: a dead/clamped page
-        # clamps its scale block identically, so payload and scale DMAs
-        # always refer to the same physical page
+        # scale sidecars follow their payload's page coordinate: a
+        # dead/clamped page clamps its scale block identically, so payload
+        # and scale DMAs always refer to the same physical page
         in_specs += [
-            pl.BlockSpec((1, T, 1, 1), k_index),
-            pl.BlockSpec((1, T, 1, 1), k_index),
+            pl.BlockSpec((1, T, KVH), ks_index),
+            pl.BlockSpec((1, T, KVH), ks_index),
             pl.BlockSpec((1, T, 1), act_index),
         ]
-        operands += [k_scales, v_scales, act_scales]
+        # widened to f32 here: the chip cannot load an f16 tile whose last
+        # dim is 1 (the per-token ACT scale), and the sidecars are tiny
+        operands += [k_scales.reshape(P_kv, T, KVH).astype(jnp.float32),
+                     v_scales.reshape(P_kv, T, KVH).astype(jnp.float32),
+                     act_scales.astype(jnp.float32)]
 
     out_specs = pl.BlockSpec((1, 1, G, D), o_index)
     out_shape = jax.ShapeDtypeStruct((B, KVH, G, D), q.dtype)

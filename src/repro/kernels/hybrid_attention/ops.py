@@ -17,7 +17,7 @@ from repro.kernels.hybrid_attention.ref import hybrid_paged_attention_ref
 
 def paged_hybrid_attention(q, k_pages, v_pages, act_pages, norm_scale, wk, wv,
                            page_table, page_type, page_ntok, *,
-                           use_kernel=True, interpret=True,
+                           use_kernel=True, interpret=False,
                            pages_bound=None, **kw):
     """pages_bound: static bound on any request's used-page count (the
     scheduler owns the page tables and knows it exactly); shrinks the

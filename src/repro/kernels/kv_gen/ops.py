@@ -8,7 +8,7 @@ from repro.kernels.kv_gen.ref import kv_gen_ref
 
 
 def kv_gen_pages(act_pages, norm_scale, wk, wv, *, norm_type="rmsnorm",
-                 eps=1e-6, use_kernel=True, interpret=True):
+                 eps=1e-6, use_kernel=True, interpret=False):
     """Recompute (K, V) for a batch of 16-token ACT pages (paper Eq. 7).
 
     On TPU call with interpret=False; on CPU either interpret=True (kernel

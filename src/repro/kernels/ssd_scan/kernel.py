@@ -58,7 +58,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dt, A, B, C, *, chunk: int = 64, interpret: bool = True):
+def ssd_scan(x, dt, A, B, C, *, chunk: int = 64, interpret: bool = False):
     """x (b,s,h,p), dt (b,s,h) positive, A (h,) negative, B/C (b,s,n) (g=1).
 
     -> y (b,s,h,p).  Sequence length must be a multiple of ``chunk`` (caller

@@ -1,7 +1,14 @@
-"""Serving launcher: the HybridServe engine on a reduced model (CPU-real).
+"""Serving launcher: the HybridServe engine or the continuous server.
 
   PYTHONPATH=src python -m repro.launch.serve --arch opt-6.7b-reduced \
       --requests 8 --mode hybrid
+
+Runs on whatever JAX's default backend is: a reduced config on CPU, or a
+published one on a TPU.  Weights are built on the host; when they would take
+more than ``OFFLOAD_FRACTION`` of the device's memory they stay there and
+stream per layer (the offload runtime, DESIGN.md §8), otherwise they are
+placed on the device (or its mesh) once.  The persistent compilation cache
+is ``$JAX_COMPILATION_CACHE_DIR`` when set, else ``<checkout>/.jax_cache``.
 
 Mesh-sharded serving (DESIGN.md §11): pass ``--mesh data,model`` to run the
 same engine tensor-parallel.  On a CPU-only box force host devices first:
@@ -24,9 +31,15 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.core import costmodel as cm
 from repro.data import request_trace
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import model as M
 from repro.serving import HybridServeEngine, exact_reference_generate
+
+#: weights above this share of device memory leave too little room for the
+#: cache and the step's temporaries: serve them from host memory instead
+OFFLOAD_FRACTION = 0.75
 
 
 def main(argv=None):
@@ -66,8 +79,12 @@ def main(argv=None):
         if args.trace:
             tracer = Tracer()
 
+    use_compile_cache()
     cfg = get_config(args.arch)
-    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    hw = cm.local_hardware()
+    dev = jax.devices()[0]
+    print(f"device: {dev.platform} {dev.device_kind} x{jax.device_count()}")
+    params = M.init_params(cfg, jax.random.PRNGKey(0), on_host=True)
     data, model_ax = (int(x) for x in args.mesh.split(","))
     plan = None
     if (data, model_ax) != (1, 1) or args.explain_plan:
@@ -79,6 +96,14 @@ def main(argv=None):
               plan.explain().splitlines()[0])
         if args.explain_plan:
             return None, None
+    shards = plan.shard_factor if plan is not None else 1
+    offload = (cfg.num_params() * cfg.bytes_per_param()
+               > OFFLOAD_FRACTION * hw.device_mem * shards)
+    if offload:
+        print("weights exceed the device budget: streaming from host memory")
+    else:
+        params = (plan.place_params(params) if plan is not None
+                  else jax.device_put(params))
     reqs = request_trace(cfg.vocab_size, args.requests,
                          prompt_mean=args.prompt_mean,
                          gen_tokens=args.gen_tokens, seed=1)
@@ -86,8 +111,8 @@ def main(argv=None):
         from repro.serving import ContinuousBatchingServer
         eng = ContinuousBatchingServer(cfg, params, slots=4,
                                        chunk_steps=args.chunk_steps,
-                                       plan=plan, tracer=tracer,
-                                       metrics=metrics)
+                                       plan=plan, offload=offload,
+                                       tracer=tracer, metrics=metrics)
         print(f"continuous batching: 4 slots, chunk_steps="
               f"{args.chunk_steps}, act_frac={eng.act_frac:.2f}")
         t0 = time.time()
@@ -105,14 +130,14 @@ def main(argv=None):
         _export_obs(args, eng, tracer)
         return out, stats
     eng = HybridServeEngine(cfg, params, mode=args.mode, plan=plan,
-                            tracer=tracer, metrics=metrics)
+                            offload=offload, tracer=tracer, metrics=metrics)
     print(f"engine: mode={args.mode} host ACT:KV ratio="
           f"{eng.alloc.act_blocks}:{eng.alloc.kv_blocks} (act_frac={eng.act_frac:.2f})")
     t0 = time.time()
     out, stats = eng.generate(reqs)
     wall = time.time() - t0
     print(f"generated {stats.generated_tokens} tokens in {stats.steps} steps "
-          f"({wall:.1f}s wall on CPU)")
+          f"({wall:.1f}s wall on {dev.platform})")
     print(f"simulated on {eng.hw.name}: throughput={stats.sim_throughput:.1f} tok/s "
           f"gpu_util={stats.sim_gpu_util:.1%}")
     if stats.traffic:

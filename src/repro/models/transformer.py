@@ -129,16 +129,41 @@ def _layer(rng, cfg, kind: str, moe: bool, cross: bool = False) -> Dict[str, Any
     return p
 
 
-def _stack(rng, n: int, make) -> Any:
-    """Stack n independently-initialised param subtrees along axis 0."""
+def _stack(rng, n: int, make, on_host: bool = False) -> Any:
+    """Stack n independently-initialised param subtrees along axis 0.
+
+    ``on_host``: copy each subtree out to host numpy as soon as it is made,
+    into one preallocated stacked array per leaf — the device holds one
+    layer at a time instead of n layers plus their stacked copy."""
     rngs = jax.random.split(rng, max(n, 1))
-    trees = [make(rngs[i], i) for i in range(n)]
-    if not trees:
-        return None
-    return jax.tree.map(lambda *xs: jnp.stack(xs, 0), *trees)
+    if not on_host:
+        trees = [make(rngs[i], i) for i in range(n)]
+        if not trees:
+            return None
+        return jax.tree.map(lambda *xs: jnp.stack(xs, 0), *trees)
+    out = None
+    for i in range(n):
+        tree = jax.device_get(make(rngs[i], i))
+        if out is None:
+            out = jax.tree.map(
+                lambda a: np.empty((n,) + a.shape, a.dtype), tree)
+        for dst, a in zip(jax.tree.leaves(out), jax.tree.leaves(tree)):
+            dst[i] = a
+    return out
 
 
-def init_params(cfg: ModelConfig, rng) -> Dict[str, Any]:
+def init_params(cfg: ModelConfig, rng, *, on_host: bool = False
+                ) -> Dict[str, Any]:
+    """Random parameters for ``cfg``.
+
+    ``on_host=True`` returns every leaf as a host numpy array with the same
+    values: each layer is made on the default device and copied out before
+    the next, so a model larger than the device can be built for the
+    offload runtime (which streams layers from host memory) or placed
+    straight onto a mesh, and a device-resident model costs the device one
+    copy of its weights instead of two."""
+    if on_host and family(cfg) != "uniform":
+        raise NotImplementedError("on_host init covers uniform-family models")
     r = jax.random.split(rng, 8)
     V = pad_vocab(cfg.vocab_size)
     params: Dict[str, Any] = {
@@ -152,9 +177,12 @@ def init_params(cfg: ModelConfig, rng) -> Dict[str, Any]:
 
     fam = family(cfg)
     moe_flags = cfg.layer_is_moe()
+    if on_host:
+        params = jax.device_get(params)
     if fam == "uniform":
         params["layers"] = _stack(
-            r[4], cfg.num_layers, lambda rg, i: _layer(rg, cfg, "attn", moe_flags[i]))
+            r[4], cfg.num_layers,
+            lambda rg, i: _layer(rg, cfg, "attn", moe_flags[i]), on_host)
     elif fam == "ssm":
         params["layers"] = _stack(
             r[4], cfg.num_layers, lambda rg, i: _layer(rg, cfg, "ssd", False))

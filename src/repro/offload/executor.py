@@ -139,7 +139,9 @@ class OffloadExecutor:
                 self.pool, prefetch_depth=prefetch_depth,
                 timeline=self.timeline, faults=faults, watchdog_s=watchdog_s,
                 max_retries=max_copy_retries, metrics=metrics)
-            self.resident = self.pool.resident
+            # committed once: a host-built (numpy) remainder would
+            # otherwise cross the link on every dispatch
+            self.resident = jax.device_put(self.pool.resident)
         self.dispatches = 0                     # jit calls (device round trips)
         # blocking host materialisation points (block_until_ready / D2H
         # reads): the layer-streamed loops block once per layer by

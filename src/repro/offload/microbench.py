@@ -40,7 +40,10 @@ from repro.offload.executor import OffloadExecutor
 #: contention instead of the runtime.  The microbenchmark therefore pins
 #: compute to ONE core — the stand-in accelerator — leaving one for the copy
 #: stream, by re-running itself in a subprocess with these flags (they must
-#: be set before jax initialises, hence the subprocess).
+#: be set before jax initialises, hence the subprocess).  The child is held
+#: to the CPU backend explicitly.  On an accelerator none of this applies —
+#: the copy engine is not a CPU core, and the parent holds the device — so
+#: the measurement runs in-process there.
 BENCH_XLA_FLAGS = "--xla_cpu_multi_thread_eigen=false intra_op_parallelism_threads=1"
 
 
@@ -51,6 +54,7 @@ def _flags_active() -> bool:
 def _run_isolated(kwargs: Dict) -> Dict[str, float]:
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + BENCH_XLA_FLAGS).strip()
+    env["JAX_PLATFORMS"] = "cpu"
     src_root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     env["PYTHONPATH"] = os.pathsep.join(
@@ -119,15 +123,17 @@ def weight_stream_microbench(cfg: Optional[ModelConfig] = None, *,
 
     Each regime is measured ``reps`` times and the MIN reported — on a
     small shared CPU the compute lane jitters by tens of ms, which would
-    otherwise drown the overlap saving.  ``isolate=True`` (default)
-    re-runs the measurement in a subprocess with ``BENCH_XLA_FLAGS`` unless
-    those flags are already active — see the note on the constant.  Up to
+    otherwise drown the overlap saving.  ``isolate=True`` (default) on the
+    CPU backend re-runs the measurement in a subprocess with
+    ``BENCH_XLA_FLAGS`` unless those flags are already active — see the
+    note on the constant; on an accelerator it measures in-process.  Up to
     ``attempts`` fresh subprocesses run until one observes positive saving:
     container CPU-bandwidth throttling (cfs quota debt from earlier work)
     intermittently denies the second core, and with one effective core
     overlap is physically impossible regardless of the runtime — the claim
     under measurement is about the runtime, not the quota scheduler."""
-    if isolate and cfg is None and not _flags_active():
+    if (isolate and cfg is None and not _flags_active()
+            and jax.default_backend() == "cpu"):
         kwargs = dict(B=B, S=S, kv_cap=kv_cap, act_cap=act_cap,
                       n_steps=n_steps, prefetch_depth=prefetch_depth,
                       reps=reps, seed=seed)

@@ -91,7 +91,8 @@ class GenStats(ScalarStatsView):
 
 
 class HybridServeEngine:
-    def __init__(self, cfg: ModelConfig, params, *, hw: cm.HardwareSpec = cm.TPU_V5E,
+    def __init__(self, cfg: ModelConfig, params, *,
+                 hw: Optional[cm.HardwareSpec] = None,
                  mode: str = "hybrid", max_minibatch: int = 4,
                  kv_cap: int = 512, act_cap: int = 512, seed: int = 0,
                  generalized: bool = False, offload: bool = False,
@@ -102,7 +103,11 @@ class HybridServeEngine:
                  plan: Optional[ShardPlan] = None,
                  tracer=None, metrics=None, quant=None,
                  host_attn: bool = False):
-        """generalized=True uses the byte-ratio-aware Algorithm-1 variant
+        """hw: the machine the policy stack plans for; default
+        ``costmodel.local_hardware()`` — the TPU this process runs on, or
+        the v5e prior on any other backend.
+
+        generalized=True uses the byte-ratio-aware Algorithm-1 variant
         (DESIGN.md §7) — recommended for GQA models; False reproduces the
         paper's policy exactly.
 
@@ -156,13 +161,15 @@ class HybridServeEngine:
         self.plan = plan
         self.quant = quant
         shards = plan.shard_factor if plan is not None else 1
-        hw = cm.scale_for_shards(hw, shards)
+        hw = cm.scale_for_shards(
+            hw if hw is not None else cm.local_hardware(), shards)
         self.cfg, self.params, self.hw, self.mode = cfg, params, hw, mode
         self.max_minibatch = max_minibatch
         self.kv_cap, self.act_cap = kv_cap, act_cap
         self.rng = np.random.default_rng(seed)
         self.offload = offload
-        self.budget = budget if budget is not None else offload_budget(cfg)
+        self.budget = (budget if budget is not None
+                       else offload_budget(cfg, hw))
 
         # observability (DESIGN.md §13) — all host-side, zero dispatches:
         # the tracer records request/lane lifecycle (NULL_TRACER = off, the
@@ -617,20 +624,26 @@ def exact_reference_generate(cfg, params, requests: List[Request]) -> Dict[int, 
 
     Uses the same scan-based device-resident loop as the engine (M.decode_loop)
     so the oracle is a single decode dispatch per request rather than one per
-    token; the prefill cache is donated into the loop."""
+    token; the prefill cache is donated into the loop.  The params are a jit
+    argument, never a captured constant, so they keep whatever placement the
+    caller committed (one device, or a mesh)."""
     out = {}
+    prefill = functools.partial(jax.jit, static_argnames=("max_len",))(
+        lambda p, toks, max_len: M.prefill(p, cfg, {"tokens": toks},
+                                           max_len=max_len))
     loop = functools.partial(jax.jit, static_argnames=("n_steps",),
-                             donate_argnums=(1,))(
-        functools.partial(M.decode_loop, params, cfg))
+                             donate_argnums=(2,))(
+        lambda p, cur, cache, n_steps: M.decode_loop(p, cfg, cur, cache,
+                                                     n_steps))
     for r in requests:
         plen = len(r.prompt)
         pb = bucket(plen)
         toks = np.zeros((1, pb), np.int32)
         toks[0, :plen] = r.prompt
         toks[0, plen:] = r.prompt[-1]
-        lg, cache = M.prefill(params, cfg, {"tokens": jnp.asarray(toks)},
-                              max_len=pb + r.max_new_tokens + 8)
+        lg, cache = prefill(params, jnp.asarray(toks),
+                            max_len=pb + r.max_new_tokens + 8)
         cur = jnp.argmax(lg[:, -1], -1).astype(jnp.int32)
-        gen, _ = loop(cur, cache, n_steps=r.max_new_tokens)
+        gen, _ = loop(params, cur, cache, n_steps=r.max_new_tokens)
         out[r.rid] = np.asarray(gen, np.int32)[0]
     return out

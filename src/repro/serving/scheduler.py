@@ -112,7 +112,8 @@ class ContinuousBatchingServer:
     def __init__(self, cfg: ModelConfig, params, *, slots: int = 4,
                  kv_cap: int = 256, act_cap: int = 256,
                  chunk_steps: int = 1,
-                 hw: cm.HardwareSpec = cm.TPU_V5E, generalized: bool = True,
+                 hw: Optional[cm.HardwareSpec] = None,
+                 generalized: bool = True,
                  offload: bool = False, prefetch_depth: int = 1,
                  adaptive: bool = False,
                  ctl: Optional[ControllerConfig] = None,
@@ -132,12 +133,19 @@ class ContinuousBatchingServer:
         arrivals may wait up to S steps for admission (TTFT cost under
         bursty traffic; see DESIGN.md §10).
 
+        hw: the machine the policy stack plans for; default
+        ``costmodel.local_hardware()`` — the TPU this process runs on, or
+        the v5e prior on any other backend.
+
         offload=True swaps the jitted monolithic decode chunk for the
         layer-streamed offload executor (DESIGN.md §8): weights arrive over
         the copy stream each iteration while the slots' KV Gen runs, with
         the streamer's prefetch window spanning the whole chunk, and
         ``self.measured_steps`` exposes the measured per-iteration lane
-        timelines.  Tokens are identical either way.
+        timelines.  Admission prefill streams the layers too, so the full
+        parameter set (which may be host numpy, ``init_params(...,
+        on_host=True)``) never reaches the device.  Tokens are identical
+        either way.
 
         adaptive=True runs the hybrid-cache controller between chunks
         (DESIGN.md §9): per-chunk timeline batches (measured under offload,
@@ -192,7 +200,8 @@ class ContinuousBatchingServer:
         self.plan = plan
         self.quant = quant
         shards = plan.shard_factor if plan is not None else 1
-        hw = cm.scale_for_shards(hw, shards)
+        hw = cm.scale_for_shards(
+            hw if hw is not None else cm.local_hardware(), shards)
         self.cfg, self.params, self.hw = cfg, params, hw
         self.n_slots, self.kv_cap, self.act_cap = slots, kv_cap, act_cap
         self.chunk_steps = max(int(chunk_steps), 1)
@@ -243,9 +252,6 @@ class ContinuousBatchingServer:
         self.cache = M.init_hybrid_cache(cfg, slots, kv_cap, act_cap)
         if plan is not None:
             self.cache = plan.place_cache(self.cache)
-            # the admission jit keeps the params resident either way
-            # (offload included); commit them to the mesh once
-            self.params = plan.place_params(params)
         self.slots = [SlotState() for _ in range(slots)]
         self.executor = None
         if offload:
@@ -256,17 +262,24 @@ class ContinuousBatchingServer:
                                             watchdog_s=watchdog_s,
                                             tracer=tracer, metrics=metrics,
                                             quant=quant)
+            # the executor owns the weights (host layer shards + resident
+            # remainder) and runs admission prefill layer by layer too: the
+            # full parameter set never reaches the device
+            self.params = None
+            self._write_rows_jit = jax.jit(self._write_rows_impl,
+                                           donate_argnums=(0,))
         else:
+            if plan is not None:
+                self.params = plan.place_params(params)
             # cache donated: the slot pools update in place every chunk
             self._decode_chunk_jit = functools.partial(
                 jax.jit, static_argnames=("kv_bound", "act_bound"),
                 donate_argnums=(2,))(self._decode_chunk_impl)
-        # admission is one jitted call per boundary: batched prefill + greedy
-        # sample + slot-row writes, cache donated (offload mode included —
-        # the scheduler keeps the params resident either way)
-        self._admit_jit = functools.partial(
-            jax.jit, static_argnames=("kv_cap", "act_cap"),
-            donate_argnums=(5,))(self._admit_impl)
+            # admission is one jitted call per boundary: batched prefill +
+            # greedy sample + slot-row writes, cache donated
+            self._admit_jit = functools.partial(
+                jax.jit, static_argnames=("kv_cap", "act_cap"),
+                donate_argnums=(5,))(self._admit_impl)
         self._cur_tok = np.zeros((slots,), np.int32)
 
     @property
@@ -332,13 +345,21 @@ class ContinuousBatchingServer:
             params, self.cfg, {"tokens": tokens}, kv_cap=kv_cap,
             act_cap=act_cap, kv_keep=kv_keep, last_pos=last_pos,
             quant=self.quant)
+        return (jnp.argmax(lg[:, -1], -1).astype(jnp.int32),
+                self._write_rows_impl(cache, c1, slot_idx))
+
+    def _write_rows_impl(self, cache, rows, slot_idx):
+        """Scatter a batch's prefilled cache rows into the slot cache (the
+        offload executor's rows arrive as per-layer lists)."""
         for key in ("k", "v", "act"):
-            cache[key] = cache[key].at[:, slot_idx].set(c1[key])
+            r = rows[key]
+            cache[key] = cache[key].at[:, slot_idx].set(
+                jnp.stack(r, 0) if isinstance(r, list) else r)
         for key in ("act_pos", "kv_len", "act_len"):
-            cache[key] = cache[key].at[slot_idx].set(c1[key])
+            cache[key] = cache[key].at[slot_idx].set(rows[key])
         if self.plan is not None:
             cache = self.plan.constrain_cache(cache)
-        return jnp.argmax(lg[:, -1], -1).astype(jnp.int32), cache
+        return cache
 
     def _decode_chunk_impl(self, params, cur, cache, store_sched,
                            active_sched, kv_bound, act_bound):
@@ -479,13 +500,26 @@ class ContinuousBatchingServer:
                 tspans.enter_context(self.tracer.request_span(
                     reqs[j].rid,
                     "resume_prefill" if pk is not None else "prefill"))
-            with trace_ctx(self.plan):
-                cur, self.cache = self._admit_jit(
-                    self.params, jnp.asarray(toks), jnp.asarray(kv_keep),
-                    jnp.asarray(np.asarray(lens, np.int32)),
-                    jnp.asarray(slot_idx),
-                    self.cache, kv_cap=self.kv_cap, act_cap=self.act_cap)
-        stats.device_calls += 1
+            if self.executor is not None:
+                # layer-streamed prefill, then ONE row-scatter dispatch
+                d0, b0 = (self.executor.dispatches,
+                          self.executor.blocking_syncs)
+                cur, rows = self.executor.prefill_batched(
+                    toks, kv_keep, np.asarray(lens, np.int32),
+                    kv_cap=self.kv_cap, act_cap=self.act_cap)
+                with trace_ctx(self.plan):
+                    self.cache = self._write_rows_jit(
+                        self.cache, rows, jnp.asarray(slot_idx))
+                stats.device_calls += self.executor.dispatches - d0 + 1
+                stats.host_syncs += self.executor.blocking_syncs - b0
+            else:
+                with trace_ctx(self.plan):
+                    cur, self.cache = self._admit_jit(
+                        self.params, jnp.asarray(toks), jnp.asarray(kv_keep),
+                        jnp.asarray(np.asarray(lens, np.int32)),
+                        jnp.asarray(slot_idx),
+                        self.cache, kv_cap=self.kv_cap, act_cap=self.act_cap)
+                stats.device_calls += 1
         stats.admission_batches += 1
         stats.admitted += k
         cur_np = np.asarray(cur, np.int32)

@@ -1,17 +1,12 @@
 """Environment compatibility for the test suite.
 
-Two container-level gaps break collection of the seed suite, so both are
-gated here instead of importing the missing/shifted APIs directly:
-
-- ``hypothesis`` may be absent.  A deterministic random-sampling fallback
-  implements the small slice of the API the suite uses (``given`` with
-  keyword strategies, ``settings(max_examples=..., deadline=...)``,
-  ``st.integers/floats/sampled_from/booleans``).  Property tests then run
-  ``max_examples`` seeded random draws — weaker than hypothesis shrinking,
-  but the invariants still execute.
-- ``jax.sharding.AbstractMesh`` changed its constructor signature across jax
-  releases (``(sizes, names)`` vs a single ``((name, size), ...)`` tuple);
-  ``abstract_mesh`` accepts the former and translates as needed.
+``hypothesis`` may be absent, so it is gated here instead of imported
+directly.  A deterministic random-sampling fallback implements the small
+slice of the API the suite uses (``given`` with keyword strategies,
+``settings(max_examples=..., deadline=...)``,
+``st.integers/floats/sampled_from/booleans``).  Property tests then run
+``max_examples`` seeded random draws — weaker than hypothesis shrinking, but
+the invariants still execute.
 """
 from __future__ import annotations
 
@@ -92,11 +87,3 @@ except ImportError:                                # pragma: no cover
             return run
         return deco
 
-
-def abstract_mesh(axis_sizes, axis_names):
-    """AbstractMesh across jax signature revisions."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))

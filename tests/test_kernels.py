@@ -22,7 +22,7 @@ def test_kv_gen_sweep(d, kvh, hd, n, norm, dtype):
     sc = (jax.random.normal(jax.random.PRNGKey(1), (d,)) * 0.1 + 1).astype(dtype)
     wk = (jax.random.normal(jax.random.PRNGKey(2), (d, kvh, hd)) * 0.05).astype(dtype)
     wv = (jax.random.normal(jax.random.PRNGKey(3), (d, kvh, hd)) * 0.05).astype(dtype)
-    k1, v1 = kv_gen(act, sc, wk, wv, norm_type=norm)
+    k1, v1 = kv_gen(act, sc, wk, wv, norm_type=norm, interpret=True)
     k2, v2 = kv_gen_ref(act, sc, wk, wv, norm_type=norm)
     tol = 1e-5 if dtype == jnp.float32 else 8e-2   # bf16 mantissa at d=512
     np.testing.assert_allclose(np.asarray(k1, np.float32),
@@ -48,7 +48,7 @@ def test_hybrid_attention_sweep(kvh, g, d_model, norm):
     pty = jnp.array([[0, 1, 0, 1, 0], [0, 0, 1, 2, 2]], jnp.int32)
     pn = jnp.array([[16, 16, 16, 16, 9], [16, 16, 5, 0, 0]], jnp.int32)
     o1 = hybrid_paged_attention(q, ks, vs, ap, sc, wk, wv, pt, pty, pn,
-                                norm_type=norm)
+                                norm_type=norm, interpret=True)
     o2 = hybrid_paged_attention_ref(q, ks, vs, ap, sc, wk, wv, pt, pty, pn,
                                     norm_type=norm)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-5)
@@ -80,13 +80,13 @@ def test_hybrid_attention_quantized_matches_dequant_ref(kvh, g, d_model, norm):
     aq, asc = quantize(ap)
     scales = dict(k_scales=ksc, v_scales=vsc, act_scales=asc)
     o1 = hybrid_paged_attention(q, kq, vq, aq, sc, wk, wv, pt, pty, pn,
-                                norm_type=norm, **scales)
+                                norm_type=norm, interpret=True, **scales)
     o2 = hybrid_paged_attention_ref(q, kq, vq, aq, sc, wk, wv, pt, pty, pn,
                                     norm_type=norm, **scales)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-5)
     # int8 error is bounded: close to (but not equal to) the fp kernel
     ofp = hybrid_paged_attention(q, ks, vs, ap, sc, wk, wv, pt, pty, pn,
-                                 norm_type=norm)
+                                 norm_type=norm, interpret=True)
     err = float(jnp.max(jnp.abs(o1 - ofp)))
     assert 0.0 < err < 0.05
 
@@ -112,7 +112,8 @@ def test_hybrid_attention_return_lse_matches_ref(kvh, g, d_model):
     pn = jnp.array([[16, 16, 16, 16, 9], [16, 16, 5, 0, 0]], jnp.int32)
     kw = dict(norm_type="layernorm")
     o1, m1, l1 = hybrid_paged_attention(q, ks, vs, ap, sc, wk, wv, pt, pty,
-                                        pn, return_lse=True, **kw)
+                                        pn, return_lse=True, interpret=True,
+                                        **kw)
     o2, m2, l2 = hybrid_paged_attention_ref(q, ks, vs, ap, sc, wk, wv, pt,
                                             pty, pn, return_lse=True, **kw)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-5)
@@ -145,7 +146,7 @@ def test_hybrid_attention_quantized_requires_all_scales():
         hybrid_paged_attention(q, ks, ks, ap, jnp.ones(d_model),
                                jnp.zeros((d_model, kvh, D)),
                                jnp.zeros((d_model, kvh, D)),
-                               pt, pt, pt, norm_type="none",
+                               pt, pt, pt, norm_type="none", interpret=True,
                                k_scales=jnp.ones((1, T, kvh, 1),
                                                  jnp.float16))
 
@@ -176,7 +177,7 @@ def test_hybrid_attention_empty_page_compaction(pages_bound):
                     [12, 0, 0, 0, 0, 0, 0, 0]], jnp.int32)
     o1 = hybrid_paged_attention(q, ks, vs, ap, sc, wk, wv, pt, pty, pn,
                                 norm_type="layernorm",
-                                pages_bound=pages_bound)
+                                pages_bound=pages_bound, interpret=True)
     o2 = hybrid_paged_attention_ref(q, ks, vs, ap, sc, wk, wv, pt, pty, pn,
                                     norm_type="layernorm")
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-5)
@@ -198,7 +199,8 @@ def test_hybrid_attention_pages_bound_guard():
         paged_hybrid_attention(q, ks, vs, ap, jnp.ones(d_model),
                                jnp.zeros((d_model, kvh, D)),
                                jnp.zeros((d_model, kvh, D)),
-                               pt, pty, pn, norm_type="none", pages_bound=1)
+                               pt, pty, pn, norm_type="none", pages_bound=1,
+                               interpret=True)
 
 
 def test_hybrid_attention_act_heavy_table():
@@ -216,7 +218,7 @@ def test_hybrid_attention_act_heavy_table():
     pn = jnp.array([[16, 16, 16], [16, 16, 7]], jnp.int32)
     for norm in ("rmsnorm", "layernorm"):
         o1 = hybrid_paged_attention(q, ks, vs, ap, sc, wk, wv, pt, pty, pn,
-                                    norm_type=norm)
+                                    norm_type=norm, interpret=True)
         o2 = hybrid_paged_attention_ref(q, ks, vs, ap, sc, wk, wv, pt, pty, pn,
                                         norm_type=norm)
         np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-5)
@@ -235,7 +237,7 @@ def test_hybrid_attention_pure_kv_matches_plain():
     pty = jnp.zeros((1, 3), jnp.int32)
     pn = jnp.array([[16, 16, 16]], jnp.int32)
     o = hybrid_paged_attention(q, ks, vs, ap, jnp.ones(d_model), wk, wk,
-                               pt, pty, pn, norm_type="none")
+                               pt, pty, pn, norm_type="none", interpret=True)
     # plain softmax reference over concatenated pages
     kcat = ks.reshape(48, kvh, D)
     vcat = vs.reshape(48, kvh, D)
@@ -254,7 +256,7 @@ def test_ssd_scan_sweep(b, s, h, p, n, chunk, dtype):
     A = -jnp.exp(jax.random.normal(rng(2), (h,)) * 0.3)
     B = jax.random.normal(rng(3), (b, s, n)) * 0.3
     C = jax.random.normal(rng(4), (b, s, n)) * 0.3
-    y1 = ssd_scan(x, dt, A, B, C, chunk=chunk)
+    y1 = ssd_scan(x, dt, A, B, C, chunk=chunk, interpret=True)
     y2 = ssd_ref_sequential(x, dt, A, B, C)
     np.testing.assert_allclose(np.asarray(y1, np.float32),
                                np.asarray(y2, np.float32), atol=2e-3)
@@ -268,7 +270,7 @@ def test_ssd_scan_bf16():
     A = -jnp.exp(jax.random.normal(rng(2), (h,)) * 0.3)
     B = jax.random.normal(rng(3), (b, s, n)) * 0.3
     C = jax.random.normal(rng(4), (b, s, n)) * 0.3
-    y1 = ssd_scan(x, dt, A, B, C, chunk=16)
+    y1 = ssd_scan(x, dt, A, B, C, chunk=16, interpret=True)
     y2 = ssd_ref_sequential(x.astype(jnp.float32), dt, A, B, C)
     np.testing.assert_allclose(np.asarray(y1, np.float32),
                                np.asarray(y2, np.float32), atol=5e-2)
@@ -289,7 +291,7 @@ def test_flash_attention_sweep(causal, window, H, KVH, dtype):
     k = jax.random.normal(jax.random.PRNGKey(1), (B, S, KVH, D)).astype(dtype)
     v = jax.random.normal(jax.random.PRNGKey(2), (B, S, KVH, D)).astype(dtype)
     o1 = flash_attention(q, k, v, causal=causal, window=window,
-                         q_chunk=16, k_chunk=16)
+                         q_chunk=16, k_chunk=16, interpret=True)
     o2 = flash_attention_ref(q, k, v, causal=causal, window=window)
     tol = 2e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(o1, np.float32),
@@ -303,6 +305,7 @@ def test_flash_attention_matches_model_path():
     q = jax.random.normal(jax.random.PRNGKey(3), (B, S, H, D))
     k = jax.random.normal(jax.random.PRNGKey(4), (B, S, KVH, D))
     v = jax.random.normal(jax.random.PRNGKey(5), (B, S, KVH, D))
-    o1 = flash_attention(q, k, v, causal=True, q_chunk=32, k_chunk=32)
+    o1 = flash_attention(q, k, v, causal=True, q_chunk=32, k_chunk=32,
+                         interpret=True)
     o2 = blockwise_attention(q, k, v, causal=True, q_chunk=32, k_chunk=32)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=2e-5)

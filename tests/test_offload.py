@@ -13,6 +13,7 @@ from _compat import given, settings, st
 
 from repro.configs import get_config
 from repro.configs.offload import OffloadBudget, offload_budget
+from repro.core import costmodel as cm
 from repro.core.blocks import (BlockManager, BlockType, Location,
                                kv_block_bytes)
 from repro.core.pipeline import MiniBatchSpec, TimelineResult, simulate_steps
@@ -55,7 +56,7 @@ def test_offload_token_exact_prefetch_depths(setup_opt, depth):
     """Streamed execution at prefetch depth 0 (synchronous), 1 (double
     buffered) and 2 must emit the exact tokens of the monolithic scan."""
     cfg, params, reqs, ref = setup_opt
-    budget = offload_budget(cfg)
+    budget = offload_budget(cfg, cm.TPU_V5E)
     eng = HybridServeEngine(
         cfg, params, mode="hybrid", max_minibatch=4, kv_cap=128, act_cap=128,
         offload=True,

@@ -3,7 +3,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _compat import abstract_mesh as AbstractMesh, given, settings, st
+from jax.sharding import AbstractMesh
+
+from _compat import given, settings, st
 
 from repro.models import shardhints as SH
 
