@@ -45,7 +45,7 @@ from repro.offload.host_attn import HostAttnExecutor, merge_partials
 from repro.offload.host_pool import HostWeightPool, Region, ShardedRegion
 from repro.offload.streamer import (ShardedWeightLanes, WeightStreamer,
                                     donate_buffers)
-from repro.offload.timeline import MeasuredTimeline
+from repro.offload.timeline import HOST, MeasuredTimeline
 
 Cache = Dict[str, Any]
 
@@ -380,12 +380,11 @@ class OffloadExecutor:
         self.streamer.begin(range(cfg.num_layers))
         for l in range(cfg.num_layers):
             lp = self.streamer.acquire(l)
-            t0 = time.perf_counter()
-            x, kc, vc, ac = self._prefill_layer(lp, x, sincos, kv_keep,
-                                                kv_cap=kv_cap, act_cap=act_cap)
-            jax.block_until_ready(x)
+            with self.timeline.task("gpu", "fwd"):
+                x, kc, vc, ac = self._prefill_layer(
+                    lp, x, sincos, kv_keep, kv_cap=kv_cap, act_cap=act_cap)
+                jax.block_until_ready(x)
             self.blocking_syncs += 1
-            self.timeline.record("gpu", "fwd", t0, time.perf_counter())
             self.dispatches += 1
             self.streamer.release(l)
             ks.append(kc); vs.append(vc); acs.append(ac)
@@ -690,28 +689,26 @@ class OffloadExecutor:
         """One host-attend layer against the spilled arena: the KV region
         never crosses the link — only the query (D2H), the merged softmax
         statistics (H2D) and the new row's store-back (D2H) do."""
-        t0 = time.perf_counter()
-        q, k0, v0, k_store, v_store, act_store = self._ha_qk(lp, h, sn)
-        q_np = self._q_host(q)
+        tl = self.timeline
+        with tl.task("gpu", "fwd"):
+            q, k0, v0, k_store, v_store, act_store = self._ha_qk(lp, h, sn)
+            q_np = self._q_host(q)
         self.blocking_syncs += 1
-        self.timeline.record("gpu", "fwd", t0, time.perf_counter())
         self.dispatches += 1
         job = lane.submit(q_np, hk_l, hv_l, kv_len_np)
-        t0 = time.perf_counter()        # device partial overlaps the cpu job
-        o_d, m_d, l_d, ac2 = self._ha_dev_partial(
-            lp, ac, act_len, store, sa, q, k0, v0, k_store, v_store,
-            act_store)
-        jax.block_until_ready(o_d)
+        with tl.task("gpu", "fwd"):     # device partial overlaps the cpu job
+            o_d, m_d, l_d, ac2 = self._ha_dev_partial(
+                lp, ac, act_len, store, sa, q, k0, v0, k_store, v_store,
+                act_store)
+            jax.block_until_ready(o_d)
         self.blocking_syncs += 1
-        self.timeline.record("gpu", "fwd", t0, time.perf_counter())
         self.dispatches += 1
         o_h, m_h, l_h = lane.collect(job)
-        t0 = time.perf_counter()
-        h = self._ha_merge(lp, h, o_d, m_d, l_d, jnp.asarray(o_h),
-                           jnp.asarray(m_h), jnp.asarray(l_h))
-        jax.block_until_ready(h)
+        with tl.task("gpu", "fwd"):
+            h = self._ha_merge(lp, h, o_d, m_d, l_d, jnp.asarray(o_h),
+                               jnp.asarray(m_h), jnp.asarray(l_h))
+            jax.block_until_ready(h)
         self.blocking_syncs += 1
-        self.timeline.record("gpu", "fwd", t0, time.perf_counter())
         self.dispatches += 1
         self._ha_store_back(k_store, v_store, hk_l, hv_l, kv_len_np,
                             store_np)
@@ -722,28 +719,26 @@ class OffloadExecutor:
         """One host-attend layer over a stacked device cache (chunked
         scheduler): the cpu lane attends over the chunk's host MIRROR of
         the KV region while the device cache stays source of truth."""
-        t0 = time.perf_counter()
-        q, k0, v0, k_store, v_store, act_store = self._ha_qk(lp, h, sn)
-        q_np = self._q_host(q)
+        tl = self.timeline
+        with tl.task("gpu", "fwd"):
+            q, k0, v0, k_store, v_store, act_store = self._ha_qk(lp, h, sn)
+            q_np = self._q_host(q)
         self.blocking_syncs += 1
-        self.timeline.record("gpu", "fwd", t0, time.perf_counter())
         self.dispatches += 1
         job = lane.submit(q_np, hk_np, hv_np, kv_len_np)
-        t0 = time.perf_counter()        # device partial overlaps the cpu job
-        o_d, m_d, l_d, kc2, vc2, ac2 = self._ha_dev_partial_kv(
-            lp, kc, vc, ac, kv_len, act_len, store, sa, q, k0, v0, k_store,
-            v_store, act_store, act_bound=act_bound)
-        jax.block_until_ready(o_d)
+        with tl.task("gpu", "fwd"):     # device partial overlaps the cpu job
+            o_d, m_d, l_d, kc2, vc2, ac2 = self._ha_dev_partial_kv(
+                lp, kc, vc, ac, kv_len, act_len, store, sa, q, k0, v0,
+                k_store, v_store, act_store, act_bound=act_bound)
+            jax.block_until_ready(o_d)
         self.blocking_syncs += 1
-        self.timeline.record("gpu", "fwd", t0, time.perf_counter())
         self.dispatches += 1
         o_h, m_h, l_h = lane.collect(job)
-        t0 = time.perf_counter()
-        h = self._ha_merge(lp, h, o_d, m_d, l_d, jnp.asarray(o_h),
-                           jnp.asarray(m_h), jnp.asarray(l_h))
-        jax.block_until_ready(h)
+        with tl.task("gpu", "fwd"):
+            h = self._ha_merge(lp, h, o_d, m_d, l_d, jnp.asarray(o_h),
+                               jnp.asarray(m_h), jnp.asarray(l_h))
+            jax.block_until_ready(h)
         self.blocking_syncs += 1
-        self.timeline.record("gpu", "fwd", t0, time.perf_counter())
         self.dispatches += 1
         rows_k = np.asarray(k_store)
         rows_v = np.asarray(v_store)
@@ -826,12 +821,12 @@ class OffloadExecutor:
                     kc, vc = self._kv_upload(hk[l], hv[l])
                 else:
                     kc, vc = ks[l], vs[l]
-                t0 = time.perf_counter()
-                x, kc2, vc2, ac2 = self._layer(lp, kc, vc, acs[l], x, kv_len,
-                                               act_len, store, sn, sa)
-                jax.block_until_ready(x)
+                with self.timeline.task("gpu", "fwd"):
+                    x, kc2, vc2, ac2 = self._layer(lp, kc, vc, acs[l], x,
+                                                   kv_len, act_len, store,
+                                                   sn, sa)
+                    jax.block_until_ready(x)
                 self.blocking_syncs += 1
-                self.timeline.record("gpu", "fwd", t0, time.perf_counter())
                 self.dispatches += 1
                 self.streamer.release(seq)
                 seq += 1
@@ -882,13 +877,12 @@ class OffloadExecutor:
         self.streamer.begin(range(Lc))
         for l in range(Lc):
             lp = self.streamer.acquire(l)
-            t0 = time.perf_counter()
-            x, ks[l], vs[l], acs[l] = self._layer(lp, ks[l], vs[l], acs[l], x,
-                                                  kv_len, act_len, store,
-                                                  sn, sa)
-            jax.block_until_ready(x)
+            with self.timeline.task("gpu", "fwd"):
+                x, ks[l], vs[l], acs[l] = self._layer(
+                    lp, ks[l], vs[l], acs[l], x, kv_len, act_len, store,
+                    sn, sa)
+                jax.block_until_ready(x)
             self.blocking_syncs += 1
-            self.timeline.record("gpu", "fwd", t0, time.perf_counter())
             self.dispatches += 1
             self.streamer.release(l)
         logits, _, (kv_len2, act_len2) = self._post(
@@ -931,18 +925,27 @@ class OffloadExecutor:
                       truth — admission, demotion and non-host-attend
                       chunks read it unchanged.
         -> (tokens (B, n_steps) int32, next cur (B,), final stacked cache).
+
+        The compute thread's time is tiled by spans: ``host``/``unstack``,
+        then per step ``host``/``pre``, per layer the streamer's
+        ``host``/``w_wait`` and ``pcie``/``w`` hand-off and the ``gpu``/
+        ``fwd`` forward, then ``host``/``post`` (ending at the readback of
+        the step's next tokens), and last ``host``/``restack``.
         """
         cfg = self.cfg
         Lc = cfg.num_layers
+        tl = self.timeline
         sched = np.asarray(store_sched, bool)
         act_np = np.asarray(active_sched, bool)
         sched = sched & act_np
         n_steps = int(sched.shape[0])
         B = int(cur.shape[0])
-        ks, vs, acs = self._unstack(cache)
+        with tl.task(HOST, "unstack"):
+            ks, vs, acs = self._unstack(cache)
         kv_len, act_len = cache["kv_len"], cache["act_len"]
         act_pos = cache["act_pos"]
         cur = jnp.asarray(cur, jnp.int32)
+        cur_np = np.asarray(cur, np.int32)
         lane = hk_np = hv_np = kv_len_np = None
         if host_attn:
             # per-chunk host mirror of the KV region: ONE bulk D2H pull
@@ -969,11 +972,13 @@ class OffloadExecutor:
         self.streamer.begin([l for _ in range(n_steps) for l in range(Lc)])
         seq = 0
         for s in range(n_steps):
-            self.timeline.begin_step("decode")
-            store = jnp.asarray(sched[s])
-            active = jnp.asarray(act_np[s])
-            x, act_pos, sn, sa = self._pre(self.resident, cur[:, None],
-                                           kv_len, act_len, act_pos, store)
+            tl.begin_step("decode")
+            with tl.task(HOST, "pre"):
+                store = jnp.asarray(sched[s])
+                active = jnp.asarray(act_np[s])
+                x, act_pos, sn, sa = self._pre(self.resident, cur[:, None],
+                                               kv_len, act_len, act_pos,
+                                               store)
             self.dispatches += 1
             for l in range(Lc):
                 lp = self.streamer.acquire(seq)
@@ -987,33 +992,34 @@ class OffloadExecutor:
                     self.streamer.release(seq)
                     seq += 1
                     continue
-                t0 = time.perf_counter()
-                x, ks[l], vs[l], acs[l] = self._layer(
-                    lp, ks[l], vs[l], acs[l], x, kv_len, act_len, store,
-                    sn, sa, kv_bound=kv_bound, act_bound=act_bound)
-                jax.block_until_ready(x)
+                with tl.task("gpu", "fwd"):
+                    x, ks[l], vs[l], acs[l] = self._layer(
+                        lp, ks[l], vs[l], acs[l], x, kv_len, act_len, store,
+                        sn, sa, kv_bound=kv_bound, act_bound=act_bound)
+                    jax.block_until_ready(x)
                 self.blocking_syncs += 1
-                self.timeline.record("gpu", "fwd", t0, time.perf_counter())
                 self.dispatches += 1
                 self.streamer.release(seq)
                 seq += 1
-            toks.append(np.where(act_np[s], np.asarray(cur, np.int32), -1))
+            with tl.task(HOST, "post"):
+                toks.append(np.where(act_np[s], cur_np, -1))
+                _, cur, (kv_len, act_len) = self._post(
+                    self.resident, x, cur, kv_len, act_len, store, active)
+                cur_np = np.asarray(cur, np.int32)
             self.blocking_syncs += 1
-            _, cur, (kv_len, act_len) = self._post(self.resident, x, cur,
-                                                   kv_len, act_len, store,
-                                                   active)
             self.dispatches += 1
             if host_attn:
                 kv_len_np = kv_len_np + ((~sched[s]) & act_np[s]).astype(
                     kv_len_np.dtype)
-            self.timeline.end_step()
+            tl.end_step()
         out = (np.stack(toks, axis=1).astype(np.int32) if toks
                else np.zeros((B, 0), np.int32))
         final: Cache = dict(cache)
-        final.update(k=jnp.stack(ks, 0), v=jnp.stack(vs, 0),
-                     act=jnp.stack(acs, 0), act_pos=act_pos,
-                     kv_len=kv_len, act_len=act_len)
-        return out, np.asarray(cur, np.int32), final
+        with tl.task(HOST, "restack"):
+            final.update(k=jnp.stack(ks, 0), v=jnp.stack(vs, 0),
+                         act=jnp.stack(acs, 0), act_pos=act_pos,
+                         kv_len=kv_len, act_len=act_len)
+        return out, cur_np, final
 
     # ================================================================== misc
     def drain_timeline(self, tag: Optional[str] = "decode"):

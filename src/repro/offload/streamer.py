@@ -54,7 +54,7 @@ import numpy as np
 from repro.obs.metrics import CounterDictView, MetricsRegistry
 from repro.offload.faults import FaultPlan, TransientCopyError
 from repro.offload.host_pool import HostWeightPool
-from repro.offload.timeline import MeasuredTimeline
+from repro.offload.timeline import HOST, MeasuredTimeline
 
 #: the streamer's robustness-counter ladder (DESIGN.md §12)
 FAULT_COUNTER_KEYS = ("watchdog_timeouts", "copy_retries", "copy_failures",
@@ -95,9 +95,8 @@ class WeightStreamer:
     the emergency path deliberately bypasses injection, modelling the
     direct, reliable-but-serial load the degraded mode IS."""
 
-    def __init__(self, pool, *, prefetch_depth: int = 1,
-                 timeline: Optional[MeasuredTimeline] = None,
-                 device=None, shard: int = 0,
+    def __init__(self, pool, *, timeline: MeasuredTimeline,
+                 prefetch_depth: int = 1, device=None, shard: int = 0,
                  faults: Optional[FaultPlan] = None,
                  watchdog_s: Optional[float] = None, max_retries: int = 2,
                  metrics: Optional[MetricsRegistry] = None):
@@ -152,25 +151,21 @@ class WeightStreamer:
                                   kinds=("stall", "copy_fail", "slow"))
             if ev is not None:
                 if ev.kind == "copy_fail":
-                    if self.timeline is not None:
-                        self.timeline.record_event("copy_fail_injected")
+                    self.timeline.record_event("copy_fail_injected")
                     raise TransientCopyError(
                         f"injected staging failure "
                         f"(layer {layer}, shard {self.shard})")
                 if ev.kind == "stall":
                     self.counters["stalls_injected"] += 1
-                if self.timeline is not None:
-                    self.timeline.record_event(f"{ev.kind}_injected")
+                self.timeline.record_event(f"{ev.kind}_injected")
                 time.sleep(ev.seconds)
         return self._stage_into(layer, self._slots[slot])
 
     def _stage_into(self, layer: int, dst):
-        t0 = time.perf_counter()
-        jax.tree.map(np.copyto, dst, self.pool.layer(layer))
         nbytes = self.pool.layer_nbytes[layer]
-        if self.timeline is not None:
-            self.timeline.record("pcie", "w", t0, time.perf_counter(), nbytes,
-                                 shard=self.shard)
+        with self.timeline.task("pcie", "w", nbytes, self.shard,
+                                name="w_stage"):
+            jax.tree.map(np.copyto, dst, self.pool.layer(layer))
         self.uploads += 1
         self.bytes_uploaded += nbytes
         return dst
@@ -185,8 +180,7 @@ class WeightStreamer:
             self._spare = jax.tree.map(
                 lambda a: np.empty_like(a), self.pool.layer(0))
         self.counters["sync_fallbacks"] += 1
-        if self.timeline is not None:
-            self.timeline.record_event("sync_fallback")
+        self.timeline.record_event("sync_fallback")
         return self._stage_into(layer, self._spare)
 
     # ------------------------------------------------------------------- pass
@@ -232,20 +226,19 @@ class WeightStreamer:
     def acquire(self, i: int):
         """Device weights for schedule position ``i``: wait for the staging
         copy (bounded by the watchdog, retried on transient failure), then
-        hand the slot off to the device (serial tail)."""
+        hand the slot off to the device (serial tail).  The caller's wait is
+        a ``host``/``w_wait`` span; the hand-off rides the pcie lane as a
+        ``w`` span with no byte count (``offload.w_handoff``)."""
         if i in self._live:
             return self._live[i]
-        if self.degraded:
-            staged = self._stage_emergency(self._sched[i])
-        else:
-            staged = self._acquire_staged(i)
-        t0 = time.perf_counter()
-        dev = (jax.device_put(staged) if self.device is None
-               else jax.device_put(staged, self.device))
-        jax.block_until_ready(dev)
-        if self.timeline is not None:       # hand-off rides the pcie lane too
-            self.timeline.record("pcie", "w", t0, time.perf_counter(), 0,
-                                 shard=self.shard)
+        tl = self.timeline
+        with tl.task(HOST, "w_wait", shard=self.shard):
+            staged = (self._stage_emergency(self._sched[i]) if self.degraded
+                      else self._acquire_staged(i))
+        with tl.task("pcie", "w", shard=self.shard, name="w_handoff"):
+            dev = (jax.device_put(staged) if self.device is None
+                   else jax.device_put(staged, self.device))
+            jax.block_until_ready(dev)
         self._live[i] = dev
         if not self.degraded:               # degraded: no prefetch top-up
             for j in range(i + 1, min(i + 1 + self.depth, len(self._sched))):
@@ -278,21 +271,18 @@ class WeightStreamer:
                 staged = fut.result(timeout=self.watchdog_s)
             except FuturesTimeout:
                 self.counters["watchdog_timeouts"] += 1
-                if self.timeline is not None:
-                    self.timeline.record_event("watchdog_timeout")
+                self.timeline.record_event("watchdog_timeout")
                 self._degrade(i)
                 return self._stage_emergency(layer)
             except TransientCopyError:
                 retries += 1
                 if retries > self.max_retries:
                     self.counters["copy_failures"] += 1
-                    if self.timeline is not None:
-                        self.timeline.record_event("copy_give_up")
+                    self.timeline.record_event("copy_give_up")
                     self._degrade(i)
                     return self._stage_emergency(layer)
                 self.counters["copy_retries"] += 1
-                if self.timeline is not None:
-                    self.timeline.record_event("copy_retry")
+                self.timeline.record_event("copy_retry")
                 time.sleep(min(0.001 * (2 ** (retries - 1)), 0.05))
                 self._staging[i] = self._stream.submit(
                     self._stage, layer, i % (self.depth + 1))
@@ -355,10 +345,9 @@ class ShardedWeightLanes:
     stamps, so lane seconds aggregate by max across shards downstream.
     """
 
-    def __init__(self, pool, plan, *, prefetch_depth: int = 1,
-                 timeline: Optional[MeasuredTimeline] = None,
-                 faults=None, watchdog_s: Optional[float] = None,
-                 max_retries: int = 2,
+    def __init__(self, pool, plan, *, timeline: MeasuredTimeline,
+                 prefetch_depth: int = 1, faults=None,
+                 watchdog_s: Optional[float] = None, max_retries: int = 2,
                  metrics: Optional[MetricsRegistry] = None):
         self.plan = plan
         self.pool = pool

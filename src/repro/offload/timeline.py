@@ -13,6 +13,11 @@ thread); a lock serialises appends.  A span is attributed to the step that
 is current when it *completes* — prefetches issued across a step boundary
 land in the step they finish in, a bounded attribution skew that washes out
 over a generation.
+
+``task`` is the one span primitive: it times a block into a lane span and
+opens a ``jax.profiler.TraceAnnotation`` ``offload.<name>`` over the same
+interval, so the program's spans also land in a profiler trace, on the
+device trace's clock.
 """
 from __future__ import annotations
 
@@ -21,6 +26,8 @@ import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
 from typing import List, Optional
+
+import jax
 
 from repro.core.pipeline import TimelineResult
 
@@ -32,10 +39,18 @@ TRAFFIC_TAGS = ("weights", "kv_load", "act_load", "store")
 #: HostAttnExecutor worker thread, overlapping the gpu lane in wall time.
 LANES = ("pcie", "pcie_up", "gpu", "cpu")
 
+#: the compute thread's own host work between device calls (``w_wait`` on a
+#: weight staging, ``pre``/``post`` dispatch and readback, cache
+#: ``unstack``/``restack``).  Its spans reach the tracer and the profiler
+#: so a decode step is tiled end to end, but never a step's lane totals:
+#: the simulator has no such lane, and the controller and drift monitor
+#: read ``TimelineResult`` as before.
+HOST = "host"
+
 
 @dataclass
 class Span:
-    lane: str                 # "pcie" | "pcie_up" | "gpu" | "cpu"
+    lane: str                 # one of LANES
     tag: str                  # "w" | "kv" | "act" | "st" | "gen" | "fwd" | "cpu"
     start: float              # perf_counter seconds
     end: float
@@ -110,11 +125,13 @@ class MeasuredTimeline:
     # ------------------------------------------------------------------ spans
     def record(self, lane: str, tag: str, start: float, end: float,
                nbytes: int = 0, shard: int = 0) -> None:
-        assert lane in LANES, lane
-        with self._lock:
-            if self._cur is None:           # span outside any step: open one
-                self._cur = _Step(tag="untagged", start=start)
-            self._cur.spans.append(Span(lane, tag, start, end, nbytes, shard))
+        assert lane in LANES or lane == HOST, lane
+        if lane != HOST:
+            with self._lock:
+                if self._cur is None:       # span outside any step: open one
+                    self._cur = _Step(tag="untagged", start=start)
+                self._cur.spans.append(
+                    Span(lane, tag, start, end, nbytes, shard))
         if self.tracer is not None:
             self.tracer.lane_span(lane, tag, start, end, nbytes=nbytes,
                                   shard=shard)
@@ -133,12 +150,17 @@ class MeasuredTimeline:
             self.tracer.lane_event(name)
 
     @contextmanager
-    def task(self, lane: str, tag: str, nbytes: int = 0):
+    def task(self, lane: str, tag: str, nbytes: int = 0, shard: int = 0,
+             name: Optional[str] = None):
+        """Record the block as one ``lane``/``tag`` span and annotate it in
+        the profiler's trace as ``offload.<name or tag>`` (about a
+        microsecond when no profiler runs, so it is always on)."""
         t0 = time.perf_counter()
         try:
-            yield
+            with jax.profiler.TraceAnnotation(f"offload.{name or tag}"):
+                yield
         finally:
-            self.record(lane, tag, t0, time.perf_counter(), nbytes)
+            self.record(lane, tag, t0, time.perf_counter(), nbytes, shard)
 
     # ---------------------------------------------------------------- results
     def results(self, tag: Optional[str] = None) -> List[TimelineResult]:

@@ -37,6 +37,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import time
 from contextlib import ExitStack
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
@@ -97,6 +98,12 @@ class ServeStats:
     ttft: Dict[int, float] = field(default_factory=dict)
     tbt: Dict[int, float] = field(default_factory=dict)
     completed_at: Dict[int, int] = field(default_factory=dict)  # rid -> step
+    # host clock (perf_counter) of each request's entry into the queue and
+    # of the readback that delivered its first token; the registry's
+    # wall-clock histograms (queue_wait_s, ttft_s, tbt_s) read these,
+    # while ttft/tbt above stay the simulator's
+    queued_at: Dict[int, float] = field(default_factory=dict)
+    first_token_at: Dict[int, float] = field(default_factory=dict)
 
     @property
     def throughput(self) -> float:
@@ -438,12 +445,16 @@ class ContinuousBatchingServer:
         reqs: List[Request] = []
         lens: List[int] = []      # true prefill lengths (-1: fill from pbs)
         rstats = self.recovery_stats
+        t_admit = time.perf_counter()
         for i, r, pk in assignments:
             if pk is None:
                 # fresh admission opens the request's root trace span; a
                 # resume re-enters the root its first admission opened
                 self.tracer.request_begin(r.rid, prompt_tokens=len(r.prompt),
                                           max_new=r.max_new_tokens)
+                if self.metrics is not None:
+                    self.metrics.histogram("queue_wait_s").observe(
+                        t_admit - stats.queued_at[r.rid])
                 reqs.append(r)
                 lens.append(-1)   # fresh: the padded bucket IS the prompt
                 continue
@@ -495,6 +506,7 @@ class ContinuousBatchingServer:
                                  min(self.kv_cap, tl))
         slot_idx = np.asarray([i for i, _, _ in assignments], np.int32)
         with ExitStack() as tspans:
+            tspans.enter_context(jax.profiler.TraceAnnotation("serve.admit"))
             tspans.enter_context(self.tracer.server_span("admit", batch=k))
             for j, (_, _, pk) in enumerate(assignments):
                 tspans.enter_context(self.tracer.request_span(
@@ -520,10 +532,11 @@ class ContinuousBatchingServer:
                         jnp.asarray(slot_idx),
                         self.cache, kv_cap=self.kv_cap, act_cap=self.act_cap)
                 stats.device_calls += 1
+            # the admission's spans end at the readback of its first tokens
+            cur_np = np.asarray(cur, np.int32)
+            stats.host_syncs += 1
         stats.admission_batches += 1
         stats.admitted += k
-        cur_np = np.asarray(cur, np.int32)
-        stats.host_syncs += 1
         stats.sim_time += self.hw.dispatch_overhead
         try:
             for j, (i, orig, pk) in enumerate(assignments):
@@ -717,9 +730,8 @@ class ContinuousBatchingServer:
     # ------------------------------------------------------------- one chunk
     def _run_chunk(self, n_steps: int, step_idx: int,
                    out: Dict[int, np.ndarray], stats: ServeStats) -> None:
-        """ONE decode dispatch for ``n_steps`` masked iterations, then the
-        host-side replay: block accounting, per-step pipeline simulation,
-        and sub-chunk TTFT/TBT/completion bookkeeping."""
+        """ONE decode dispatch for ``n_steps`` masked iterations and its
+        readback, then the host-side replay (``_replay_chunk``)."""
         B = self.n_slots
         remaining = np.asarray([s.remaining if s.active else 0
                                 for s in self.slots])
@@ -789,6 +801,7 @@ class ContinuousBatchingServer:
         act_bound = min(self.act_cap, bucket(int(at0.max()) + n_steps))
 
         with ExitStack() as tspans:
+            tspans.enter_context(jax.profiler.TraceAnnotation("serve.chunk"))
             tspans.enter_context(self.tracer.server_span(
                 "chunk", steps=n_steps, idx=stats.chunks))
             for i, st in enumerate(self.slots):
@@ -815,8 +828,22 @@ class ContinuousBatchingServer:
                         kv_bound=kv_bound, act_bound=act_bound)
                 stats.device_calls += 1
                 stats.host_syncs += 1  # the chunk's ONE blocking readback
-        toks_np = np.asarray(toks, np.int32)
-        self._cur_tok = np.array(cur, np.int32)     # writable host copy
+            # the chunk's spans end at the readback, not at the enqueue
+            toks_np = np.asarray(toks, np.int32)
+            self._cur_tok = np.array(cur, np.int32)     # writable host copy
+        t_read = time.perf_counter()
+        with jax.profiler.TraceAnnotation("serve.replay"):
+            self._replay_chunk(n_steps, step_idx, out, stats, toks_np,
+                               t_read, active, sched_t, kv_run, act_run)
+
+    def _replay_chunk(self, n_steps: int, step_idx: int,
+                      out: Dict[int, np.ndarray], stats: ServeStats,
+                      toks_np: np.ndarray, t_read: float, active: np.ndarray,
+                      sched_t: np.ndarray, kv_run: np.ndarray,
+                      act_run: np.ndarray) -> None:
+        """The host replay after a chunk's readback (delivered at
+        ``t_read``): block accounting, per-step pipeline simulation,
+        TTFT/TBT and retirement, then the timeline drain and metric fold."""
         stats.chunks += 1
         # the amortized tax: ONE host dispatch + blocking sync per chunk
         # (per token at chunk_steps=1) — serialized on the critical path, so
@@ -869,17 +896,20 @@ class ContinuousBatchingServer:
                             hint="grow the host pools or lower concurrency")
                     if st.rid not in stats.ttft:
                         stats.ttft[st.rid] = stats.sim_time
+                        stats.first_token_at[st.rid] = t_read
                         if self.metrics is not None:
                             self.metrics.histogram("ttft_s").observe(
-                                stats.ttft[st.rid])
+                                t_read - stats.queued_at[st.rid])
                     if st.remaining == 0:
                         out[st.rid] = np.asarray(st.generated, np.int32)
                         stats.tbt[st.rid] = stats.sim_time / max(
                             len(st.generated), 1)
                         stats.completed_at[st.rid] = step_idx + s
                         if self.metrics is not None:
+                            # the mean gap between the request's deliveries
                             self.metrics.histogram("tbt_s").observe(
-                                stats.tbt[st.rid])
+                                (t_read - stats.first_token_at[st.rid])
+                                / max(len(st.generated) - 1, 1))
                         self.tracer.request_end(
                             st.rid, "complete", tokens=len(st.generated),
                             step=step_idx + s)
@@ -930,9 +960,12 @@ class ContinuousBatchingServer:
         reaches ``arrival_steps[i]`` (the soak harness's randomised open-loop
         traffic).  Omitted, every request is queued up front (closed loop).
         """
+        stats = ServeStats()
         if arrival_steps is None:
             pending: List = []
             queue = list(requests)
+            t = time.perf_counter()
+            stats.queued_at.update((r.rid, t) for r in queue)
         else:
             assert len(arrival_steps) == len(requests)
             order = sorted(range(len(requests)),
@@ -940,12 +973,13 @@ class ContinuousBatchingServer:
             pending = [(int(arrival_steps[i]), requests[i]) for i in order]
             queue = []
         out: Dict[int, np.ndarray] = {}
-        stats = ServeStats()
         step_idx = 0
         while (queue or pending or self.parked
                or any(s.active for s in self.slots)):
             while pending and pending[0][0] <= step_idx:
-                queue.append(pending.pop(0)[1])
+                r = pending.pop(0)[1]
+                stats.queued_at[r.rid] = time.perf_counter()
+                queue.append(r)
             # chunk-boundary admission: parked resumes first, then ALL due
             # arrivals that fit, coalesced into one batched prefill dispatch
             assignments = self._plan_admission(queue)
