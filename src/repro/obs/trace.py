@@ -11,13 +11,13 @@ DESIGN.md §13):
     across preemption — park/resume land inside it — so every request's
     span tree is complete and single-rooted however often it bounced
     through the re-admission queue.
-  * LANE events — one track per (lane, shard) (pid ``PID_LANES``):
-    weight-stream staging/hand-off (``w``), spilled-KV loads (``kv``),
-    ACT loads (``act``), stores (``st``), compute (``fwd``/``gen``), and
-    instant fault/robustness markers (``copy_retry``, ``watchdog_timeout``,
-    ``sync_fallback``, ...).  These arrive through the
-    ``MeasuredTimeline`` bridge, so the offload runtime needs no second
-    instrumentation layer.
+  * LANE events — one track per (lane, shard, recording thread) (pid
+    ``PID_LANES``): weight-stream staging/hand-off (``w``), spilled-KV
+    loads (``kv``), ACT loads (``act``), stores (``st``), compute
+    (``fwd``/``gen``), and instant fault/robustness markers
+    (``copy_retry``, ``watchdog_timeout``, ``sync_fallback``, ...).
+    These arrive through the ``MeasuredTimeline`` bridge, so the offload
+    runtime needs no second instrumentation layer.
   * SERVER spans (pid ``PID_SERVER``) — chunk/admission/controller windows.
 
 Zero overhead when disabled: the module-level ``NULL_TRACER`` swallows
@@ -81,7 +81,10 @@ class Tracer:
             self._events.append(ev)
 
     def _lane_tid(self, lane: str, shard: int) -> int:
-        key = f"{lane}/{shard}"
+        # a track per recording thread too: spans on one track must nest,
+        # and the copy stream stages the next layer on the pcie lane while
+        # the compute thread hands the current one off on the same lane
+        key = f"{lane}/{shard}/{threading.current_thread().name}"
         with self._lock:
             tid = self._lane_tids.get(key)
             if tid is None:

@@ -940,6 +940,9 @@ class OffloadExecutor:
         sched = sched & act_np
         n_steps = int(sched.shape[0])
         B = int(cur.shape[0])
+        # ONE prefetch window across the whole chunk's layer sequence,
+        # opened first so layer 0's staging runs under the unstack
+        self.streamer.begin([l for _ in range(n_steps) for l in range(Lc)])
         with tl.task(HOST, "unstack"):
             ks, vs, acs = self._unstack(cache)
         kv_len, act_len = cache["kv_len"], cache["act_len"]
@@ -968,8 +971,6 @@ class OffloadExecutor:
             self.timeline.end_step()
             kv_len_np = np.asarray(cache["kv_len"]).copy()
         toks: List[np.ndarray] = []
-        # ONE prefetch window across the whole chunk's layer sequence
-        self.streamer.begin([l for _ in range(n_steps) for l in range(Lc)])
         seq = 0
         for s in range(n_steps):
             tl.begin_step("decode")

@@ -27,17 +27,27 @@ Dispatch-ahead protocol (prefetch depth ``d``):
     loop cycles ``[0..L-1]`` per step — prefetch crosses step boundaries
     so layer 0 of step ``s+1`` stages while layer ``L-1`` of step ``s``
     computes).
-  * ``acquire(i)`` blocks until staging ``i`` has landed, hands the slot
-    off to the device, then tops the in-flight window back up to ``d``
-    stagings beyond ``i``.  With ``d=0`` everything runs inline on the
-    caller thread — no overlap, the stream-only baseline.
+  * ``acquire(i)`` blocks until staging ``i`` has landed, tops the
+    in-flight window back up to ``d`` stagings beyond ``i``, then hands
+    slot ``i`` off to the device — so the copy stream stages the next
+    layers WHILE the caller thread runs ``i``'s hand-off, not after it.
+    With ``d=0`` everything runs inline on the caller thread — no
+    overlap, the stream-only baseline.
   * ``release(i)`` donates the stale buffer: every device leaf of upload
     ``i`` is deleted, bounding device residency to ``d + 1`` layer shards
     (classic double buffering at ``d=1``).
 
-Slot safety: staging slot ``i % (d+1)`` is only re-dispatched after
-``acquire(i)`` consumed it into a device buffer, so the window arithmetic
-alone guarantees no overwrite of un-handed-off data.
+Slot safety: the top-up in ``acquire(i)`` writes at most slot
+``(i+d) % (d+1) = (i-1) % (d+1)``, last held by position ``i-1``, which
+the caller handed off and released before asking for ``i`` (the
+acquire → forward → release discipline); it is never slot ``i % (d+1)``,
+the one being handed off.  A backend whose ``device_put`` aliases host
+memory therefore never sees a live device tree's slot overwritten.
+
+Engagement: every acquire counts, per shard, whether its staging had
+already ``landed`` when the caller asked for it or the caller ``waited``
+(``streamer_acquires{key,shard}``; an inline depth-0 stage and a degraded
+lane's emergency stage count as waited).
 
 ``submit`` exposes the same serialized stream for other host→device work.
 """
@@ -59,6 +69,8 @@ from repro.offload.timeline import HOST, MeasuredTimeline
 #: the streamer's robustness-counter ladder (DESIGN.md §12)
 FAULT_COUNTER_KEYS = ("watchdog_timeouts", "copy_retries", "copy_failures",
                       "sync_fallbacks", "stalls_injected")
+#: acquires whose staging had landed before the caller asked, and the rest
+ACQUIRE_COUNTER_KEYS = ("landed", "waited")
 
 
 def donate_buffers(tree) -> None:
@@ -127,17 +139,18 @@ class WeightStreamer:
         self.bytes_uploaded = 0
         self.peak_resident = 0
         self.degraded = False     # lane health: False=healthy, True=degraded
-        # robustness counters (cumulative across passes; see lane_health).
-        # With a metrics registry the dict is a live VIEW over
-        # ``streamer_faults{key=...,shard=N}`` counters — same mapping
-        # surface, one counter source of truth (DESIGN.md §13); without one
-        # it stays the old plain dict.
-        if metrics is None:
-            self.counters: Dict[str, int] = {k: 0 for k in FAULT_COUNTER_KEYS}
-        else:
-            self.counters = CounterDictView(
-                metrics, "streamer_faults", labels={"shard": shard},
-                keys=FAULT_COUNTER_KEYS)
+        # robustness counters (cumulative across passes; see lane_health)
+        # and staging engagement (``acquires``).  With a metrics registry
+        # each dict is a live VIEW over ``<name>{key=...,shard=N}``
+        # counters — same mapping surface, one counter source of truth
+        # (DESIGN.md §13); without one it stays a plain dict.
+        def counts(name: str, keys):
+            if metrics is None:
+                return {k: 0 for k in keys}
+            return CounterDictView(metrics, name, labels={"shard": shard},
+                                   keys=keys)
+        self.counters = counts("streamer_faults", FAULT_COUNTER_KEYS)
+        self.acquires = counts("streamer_acquires", ACQUIRE_COUNTER_KEYS)
 
     # ----------------------------------------------------------------- stream
     def submit(self, fn: Callable[[], object]) -> Future:
@@ -225,24 +238,29 @@ class WeightStreamer:
 
     def acquire(self, i: int):
         """Device weights for schedule position ``i``: wait for the staging
-        copy (bounded by the watchdog, retried on transient failure), then
-        hand the slot off to the device (serial tail).  The caller's wait is
-        a ``host``/``w_wait`` span; the hand-off rides the pcie lane as a
-        ``w`` span with no byte count (``offload.w_handoff``)."""
+        copy (bounded by the watchdog, retried on transient failure), top
+        the prefetch window up so the next stagings run under this one's
+        hand-off, then hand the slot off to the device (serial tail).  The
+        caller's wait is a ``host``/``w_wait`` span; the hand-off rides the
+        pcie lane as a ``w`` span with no byte count
+        (``offload.w_handoff``)."""
         if i in self._live:
             return self._live[i]
         tl = self.timeline
+        fut = self._staging.get(i)          # none on a degraded lane
+        landed = fut is not None and fut.done() and fut.exception() is None
+        self.acquires["landed" if landed else "waited"] += 1
         with tl.task(HOST, "w_wait", shard=self.shard):
             staged = (self._stage_emergency(self._sched[i]) if self.degraded
                       else self._acquire_staged(i))
+        if not self.degraded:               # degraded: no prefetch top-up
+            for j in range(i + 1, min(i + 1 + self.depth, len(self._sched))):
+                self._dispatch(j)
         with tl.task("pcie", "w", shard=self.shard, name="w_handoff"):
             dev = (jax.device_put(staged) if self.device is None
                    else jax.device_put(staged, self.device))
             jax.block_until_ready(dev)
         self._live[i] = dev
-        if not self.degraded:               # degraded: no prefetch top-up
-            for j in range(i + 1, min(i + 1 + self.depth, len(self._sched))):
-                self._dispatch(j)
         self.peak_resident = max(self.peak_resident,
                                  len(self._live) + len(self._staging))
         return dev
