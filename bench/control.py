@@ -36,14 +36,14 @@ import numpy as np
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 from bench import run as bench_run  # noqa: E402  (puts src/ on the path)
-from bench import correct, reference, spec, traffic  # noqa: E402
+from bench import correct, spec, traffic  # noqa: E402
 
 PAD = 16
 
 
 def _readings(*, cell, rest, layer, finished, rids, ref, act):
     seqs, want = correct.sequences(finished, rids)
-    ctl = reference.logits(cell.config, rest, layer, seqs, want, "fp8")
+    ctl = cell.reference.logits(cell.config, rest, layer, seqs, want, "fp8")
     ctl_checks = correct.checks(correct.control_gaps(ref, ctl),
                                 float(cell.sizes["gap_limit"]), act, 0)
     out = {"control": ctl_checks["widest_logit_gap"]["value"],
@@ -58,7 +58,7 @@ def _readings(*, cell, rest, layer, finished, rids, ref, act):
             padded[r] = (np.concatenate([p, np.full(pb - len(p), p[-1],
                                                     np.int32)]), s)
         pseqs, pwant = correct.sequences(padded, rids)
-        pref = reference.logits(cell.config, rest, layer, pseqs, pwant)
+        pref = cell.reference.logits(cell.config, rest, layer, pseqs, pwant)
         out["padded_prompt"] = float(
             correct.served_gaps(pref, padded, rids).max())
     return out

@@ -7,7 +7,10 @@ tested on small synthetic traces.  Device planes are those named
 ``/device:TPU:<n>``; their ``XLA Ops`` line holds one event per operation run
 and their ``XLA Modules`` line one event per program run (named after the
 jitted function, e.g. ``jit__decode_chunk_impl(...)``).  Host planes hold the
-host threads' events, which label the idle gaps.
+host threads' events, which label the idle gaps.  An operation's event also
+carries, as ``info``, the trace's ``long_name`` and ``hlo_category`` stats of
+it (its HLO instruction and the profiler's class of it), read once per
+operation name and program.
 """
 from __future__ import annotations
 
@@ -23,6 +26,7 @@ class Event(NamedTuple):
     name: str
     start_ns: int
     dur_ns: int
+    info: str = ""   # a device operation's INFO_STATS, space-separated
 
     @property
     def end_ns(self) -> int:
@@ -31,6 +35,11 @@ class Event(NamedTuple):
 
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
+INFO_STATS = ("long_name", "hlo_category")
+
+
+def _info(ev) -> str:
+    return " ".join(str(v) for k, v in ev.stats if k in INFO_STATS)
 
 
 def read_events(trace_dir: str) -> List[Event]:
@@ -43,10 +52,27 @@ def read_events(trace_dir: str) -> List[Event]:
     data = jax.profiler.ProfileData.from_file(files[-1])
     out: List[Event] = []
     for plane in data.planes:
-        for line in plane.lines:
+        lines = list(plane.lines)
+        device = plane.name.startswith("/device:TPU")
+        mods = sorted((int(ev.start_ns), int(ev.start_ns + ev.duration_ns),
+                       ev.name) for line in lines
+                      if device and line.name == MODULES_LINE
+                      for ev in line.events)
+        starts = [m[0] for m in mods]
+        info: Dict[Tuple[str, str], str] = {}
+        for line in lines:
+            ops = device and line.name == OPS_LINE
             for ev in line.events:
-                out.append(Event(plane.name, line.name, ev.name,
-                                 int(ev.start_ns), int(ev.duration_ns)))
+                t, text = int(ev.start_ns), ""
+                if ops:   # the same name in another program is another op
+                    i = bisect.bisect_right(starts, t) - 1
+                    key = (mods[i][2] if i >= 0 and t < mods[i][1] else "",
+                           ev.name)
+                    text = info.get(key)
+                    if text is None:
+                        text = info[key] = _info(ev)
+                out.append(Event(plane.name, line.name, ev.name, t,
+                                 int(ev.duration_ns), text))
     return out
 
 
@@ -70,17 +96,22 @@ def union(intervals: Iterable[Tuple[int, int]]) -> List[Tuple[int, int]]:
     return out
 
 
+def busy_by_plane(events: Iterable[Event]) -> Dict[str, int]:
+    """Nanoseconds in which an operation ran, for each device plane that
+    ran one."""
+    by: Dict[str, List[Tuple[int, int]]] = {}
+    for e in events:
+        if is_device(e) and e.line == OPS_LINE:
+            by.setdefault(e.plane, []).append((e.start_ns, e.end_ns))
+    return {p: sum(b - a for a, b in union(iv)) for p, iv in by.items()}
+
+
 def busy_seconds(events: Iterable[Event]) -> float:
     """Seconds in which an operation ran, averaged over the device planes."""
-    ops = [e for e in events if is_device(e) and e.line == OPS_LINE]
-    planes = {e.plane for e in ops}
-    if not planes:
+    busy = busy_by_plane(events)
+    if not busy:
         return 0.0
-    total = 0
-    for p in planes:
-        total += sum(b - a for a, b in union(
-            (e.start_ns, e.end_ns) for e in ops if e.plane == p))
-    return total / len(planes) / 1e9
+    return sum(busy.values()) / len(busy) / 1e9
 
 
 def program_of(name: str) -> str:

@@ -2,8 +2,7 @@
 the hand-offs, GB/s.  A hand-off is a ``pcie``/``w`` lane span with no byte
 count (the streamer's ``device_put`` + ``block_until_ready`` of one staged
 layer); each moves one layer's weights, counted from the configuration's
-sizes (``bench/counts.py``).  Offload cells only."""
-from bench import counts
+sizes (``Window.counts``).  Offload cells only."""
 
 
 def handoffs(w):
@@ -16,4 +15,4 @@ def read(w):
     secs = sum(s.end - s.start for s in h)
     if not h or secs <= 0:
         return None
-    return len(h) * counts.layer_bytes(w.config) / secs / 1e9
+    return len(h) * w.counts.layer_bytes(w.config) / secs / 1e9

@@ -11,6 +11,13 @@ Steps, in order:
 2. Turn on the program's persistent compilation cache (inside the checkout,
    or ``$JAX_COMPILATION_CACHE_DIR``).
 3. Make the weights from the seed on the device (``bench/weights.py``).
+   A cell that asks for more than one chip is served tensor-parallel on a
+   (1, chips) mesh (the program's ``make_test_mesh``): the program's shard
+   plan (``make_shard_plan``) is built from the weights' shapes, the
+   weights are made in its shardings, and the server is given the plan.
+   A configuration that names a ``family`` takes its weight scales, its
+   reference and its counts from ``bench/families/<family>.py``
+   (``bench/spec.py``).
 4. Warm up: serve stream 1 of the cell's traffic (``bench/traffic.py``)
    through ``ContinuousBatchingServer.run`` for ``warm_chunks`` chunks, then
    stop it and release its slots with the server's own failure-path
@@ -24,9 +31,10 @@ Steps, in order:
    ``--seconds`` (whole chunks; see ``bench/window.py``) and the run stops
    there without draining.  With ``--trace 1`` the profiler records the
    window.
-6. Read the device's peak memory, free the server, and compare the tokens
+6. Read the devices' peak memory, free the server, and compare the tokens
    it served in the warm-up and in the window with the plain reference
-   (``bench/correct.py``).
+   (``bench/correct.py``), which reads the weights layer by layer (on a
+   mesh, each layer's sharded slices).
 
 The last line of stdout is the result; the numbers compared, each beside its
 limit, are the last lines of stderr and the result's last key.
@@ -46,7 +54,7 @@ import shutil  # noqa: E402
 import sys  # noqa: E402
 import traceback  # noqa: E402
 from pathlib import Path  # noqa: E402
-from typing import Dict, Optional  # noqa: E402
+from typing import Dict, List, Optional  # noqa: E402
 
 ROOT = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT / "src"))
@@ -122,10 +130,23 @@ def note(msg: str) -> None:
     print(f"[bench] {msg}", file=sys.stderr, flush=True)
 
 
-def _peak_bytes() -> int:
+def _peak_bytes() -> List[int]:
+    """Each local device's peak bytes in use."""
     import jax
-    return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
-               for d in jax.local_devices())
+    return [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in jax.local_devices()]
+
+
+def shard_plan(cfg, chips: int):
+    """The program's shard plan on a (1, ``chips``) mesh, or None for one
+    chip."""
+    if chips == 1:
+        return None
+    from bench import weights
+    from repro.launch.mesh import make_test_mesh
+    from repro.sharding import make_shard_plan
+    return make_shard_plan(cfg, make_test_mesh(1, chips),
+                           weights.shapes_of(cfg))
 
 
 def execute(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
@@ -136,7 +157,7 @@ def execute(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     (``bench/control.py`` only) adds numbers read against the reference,
     which the result carries under ``readings``."""
     import jax
-    from bench import correct, reference, traffic, weights
+    from bench import correct, traffic, weights
     from bench.compiles import CompileClock
     from bench.tap import Stop, Tap
     from bench.window import Window
@@ -157,8 +178,16 @@ def execute(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     sz = cell.sizes
     cfg = program_config(cell)
     offload = cell.config["regime"] == "offload"
-    made = (weights.make_offload if offload else weights.make_resident)(
-        cfg, cell.config, seed)
+    plan = shard_plan(cfg, cell.chips)
+    if offload and plan is not None:
+        raise ValueError(f"{cell.name}: the harness serves offload cells on "
+                         f"one chip only")
+    if offload:
+        made = weights.make_offload(cfg, cell.config, seed,
+                                    family=cell.family)
+    else:
+        made = weights.make_resident(cfg, cell.config, seed, plan=plan,
+                                     family=cell.family)
     note(f"weights made at {time.perf_counter() - t_start:.1f} s")
 
     def requests_of(stream: int):
@@ -174,7 +203,7 @@ def execute(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     server = ContinuousBatchingServer(
         cfg, made, slots=int(sz["slots"]), kv_cap=int(sz["kv_cap"]),
         act_cap=int(sz["act_cap"]), chunk_steps=int(sz["chunk_steps"]),
-        offload=offload, tracer=tap, metrics=tap.registry)
+        offload=offload, plan=plan, tracer=tap, metrics=tap.registry)
     tap.server = server
     if mutate is not None:
         mutate(server)
@@ -237,7 +266,8 @@ def execute(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     note(f"window {st['open'] - t_start:.1f}-{st['close'] - t_start:.1f} s "
          f"after start, {len(tap.chunks)} chunks served, warm-up "
          f"{warm_chunks} chunks")
-    peak = _peak_bytes()
+    peaks_by_device = _peak_bytes()
+    peak = max(peaks_by_device)
     served.update({("window", r): (requests[r].prompt, toks)
                    for r, toks in tap.served().items()})
     act_held.update(tap.act_held("window"))
@@ -258,9 +288,12 @@ def execute(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     busy = None
     if trace:
         from bench import devtrace
+        t_read = time.perf_counter()
         w.trace = devtrace.read_events(str(trace_dir))
         w.trace_window_s = st["trace_t1"] - st["trace_t0"]
         busy = devtrace.busy_seconds(w.trace)
+        note(f"trace: {len(w.trace)} events read in "
+             f"{time.perf_counter() - t_read:.1f} s")
 
     # --- correctness ---------------------------------------------------------
     rids = correct.sample(served, seed, int(sz["check_tokens"]))
@@ -271,7 +304,7 @@ def execute(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     def layer(l):
         return jax.tree.map(lambda a: jax.numpy.asarray(a[l]), layers)
 
-    ref = reference.logits(cell.config, rest, layer, seqs, want)
+    ref = cell.reference.logits(cell.config, rest, layer, seqs, want)
     gaps = correct.served_gaps(ref, served, rids)
     act = int(sum(act_held.get(r, 0) for r in rids))
     failed = len(warm_failed) + len(tap.failed)
@@ -289,7 +322,8 @@ def execute(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
             unit = next(x["unit"] for x in wanted if x["name"] == m)
             metrics[m] = {"value": float(v), "unit": unit}
     device = {"platform": dev.platform, "kind": dev.device_kind,
-              "count": cell.chips, "memory_peak_bytes": peak}
+              "count": cell.chips, "memory_peak_bytes": peak,
+              "memory_peak_bytes_by_device": peaks_by_device[:cell.chips]}
     out = {"correct": bool(ok), "attempted": len(w.served_requests()),
            "failed": len(tap.failed), "metrics": metrics, "device": device}
     if trace:

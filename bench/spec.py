@@ -10,14 +10,25 @@
   (``check_tokens``) under which limit (``gap_limit``);
 - ``bench/configs/<config>.json``: the model and its regime, its sizes and
   its source;
-- ``bench/traffic/<mix>.json``: the traffic mix (see ``bench/traffic.py``).
+- ``bench/traffic/<mix>.json``: the traffic mix (see ``bench/traffic.py``);
+- ``bench/families/<family>.py``, where the configuration file names a
+  ``"family"``: the architecture's own plain reference, weight scales and
+  counts (see ``Cell.reference``, ``Cell.counts``, ``bench/weights.py``).
+  A configuration with no ``family`` is a dense decoder, served by
+  ``bench/reference.py``, ``weights.scale_for`` and ``bench/counts.py``.
+
+A cell whose entry asks for more than one chip is served on a mesh of that
+many chips: ``bench/run.py`` builds it and the program's shard plan, and
+makes the weights in the plan's shardings.
 """
 from __future__ import annotations
 
+import importlib.util
 import json
 from dataclasses import dataclass
 from pathlib import Path
-from typing import Dict, List
+from types import ModuleType
+from typing import Dict, List, Optional
 
 from bench import traffic
 
@@ -34,15 +45,48 @@ class Cell:
     mix: Dict
     end_to_end: List[Dict]
     per_layer: List[Dict]
+    family: Optional[ModuleType] = None   # bench/families/<family>.py
 
     @property
     def chips(self) -> int:
         return int(self.entry["chips"])
 
+    @property
+    def reference(self) -> ModuleType:
+        """The module whose ``logits`` is the plain float32 reference."""
+        if self.family is not None:
+            return self.family
+        from bench import reference
+        return reference
+
+    @property
+    def counts(self) -> ModuleType:
+        """The module whose functions count the work's FLOPs and bytes."""
+        if self.family is not None:
+            return self.family
+        from bench import counts
+        return counts
+
 
 def _load(path: Path) -> Dict:
     with open(path) as f:
         return json.load(f)
+
+
+def load_family(name: str, directory: Path = BENCH / "families"
+                ) -> ModuleType:
+    """The module ``<directory>/<name>.py``: ``logits`` (the float32
+    reference, as ``bench/reference.py``'s), ``scale_for`` (as
+    ``weights.scale_for``'s, or None to defer to it) and the functions of
+    ``bench/counts.py`` that the readers call."""
+    path = directory / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no family module {path}")
+    loc = importlib.util.spec_from_file_location(f"bench.families.{name}",
+                                                 path)
+    mod = importlib.util.module_from_spec(loc)
+    loc.loader.exec_module(mod)
+    return mod
 
 
 def covers(metric: Dict, cell: str) -> bool:
@@ -58,10 +102,13 @@ def load_cell(name: str, benchmark: Path = ROOT / "BENCHMARK.json",
     if not entries:
         raise KeyError(f"no workload {name!r} in {benchmark}")
     entry = entries[0]
+    config = _load(base / "configs" / f"{entry['config']}.json")
     return Cell(
         name=name, entry=entry,
         sizes=_load(base / "cells" / f"{name}.json"),
-        config=_load(base / "configs" / f"{entry['config']}.json"),
+        config=config,
         mix=traffic.load_mix(entry["traffic"], base / "traffic"),
         end_to_end=[m for m in bench["end_to_end"] if covers(m, name)],
-        per_layer=[m for m in bench["per_layer"] if covers(m, name)])
+        per_layer=[m for m in bench["per_layer"] if covers(m, name)],
+        family=(load_family(config["family"], base / "families")
+                if "family" in config else None))

@@ -280,6 +280,10 @@ def test_benchmark_names_files_that_exist():
         assert (BENCH / "metrics" / f"{m['name']}.py").is_file()
     assert math.isclose(
         sum(1 for w in b["workloads"] if w["chips"] == 4), 0)
+    for w in b["workloads"]:
+        config = spec.load_cell(w["name"]).config
+        assert "family" not in config or \
+            (BENCH / "families" / f"{config['family']}.py").is_file()
 
 
 # ---------------------------------------------------------- the comparison

@@ -3,14 +3,20 @@
 The tree is the one ``jax.eval_shape`` gives of the program's
 ``init_params``: the same leaves, shapes and dtypes, so the server takes it
 as it would take its own.  The values are normal draws at that function's
-scales (``scale_for``), in the type they are served in, made on the device:
+scales (``scale_for``, or the configuration's family's own first, see
+``bench/spec.py``), in the type they are served in, made on the device:
 
-- ``resident``: one jitted call makes the whole tree on the device;
+- ``resident``: one jitted call makes the whole tree on the device; on a
+  mesh, in the shard plan's shardings, each layer's draws constrained to
+  its layer's shardings, so no device holds more than its share of the
+  tree and of one layer's float32 draws;
 - ``offload``: one jitted call per layer makes that layer on the device and
   it is copied to host memory at once, into one preallocated stacked host
   array per leaf; the rest of the tree stays on the device.
 
-The draws differ from ``init_params``'s own; their scales do not.
+The draws differ from ``init_params``'s own; their scales do not.  They do
+not depend on the shardings (JAX's partitionable Threefry), so a seed makes
+the same weights on one chip and on a mesh.
 """
 from __future__ import annotations
 
@@ -51,12 +57,19 @@ def scale_for(names: Tuple[str, ...], shape, sizes: Dict[str, int]):
     raise ValueError(f"no init scale known for leaf {'/'.join(names)}")
 
 
-def _make_tree(key, shapes, sizes):
+def _scale(names, shape, sizes, family):
+    """The family's scale for a leaf where it gives one, else ours."""
+    got = family.scale_for(names, shape, sizes) if family is not None \
+        else None
+    return got if got is not None else scale_for(names, shape, sizes)
+
+
+def _make_tree(key, shapes, sizes, family=None):
     leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
     keys = jax.random.split(key, len(leaves))
     out = []
     for k, (path, sd) in zip(keys, leaves):
-        kind, std = scale_for(_names(path), sd.shape, sizes)
+        kind, std = _scale(_names(path), sd.shape, sizes, family)
         if kind == "normal":
             out.append((jax.random.normal(k, sd.shape, jnp.float32)
                         * std).astype(sd.dtype))
@@ -67,14 +80,20 @@ def _make_tree(key, shapes, sizes):
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
-def _make_stacked(key, shapes, sizes):
+def _make_stacked(key, shapes, sizes, family=None, shardings=None):
     """The stacked layer tree, one layer per iteration of a ``lax.map`` so
-    that no float32 copy of a whole stacked leaf is ever held."""
+    that no float32 copy of a whole stacked leaf is ever held.  With
+    ``shardings`` (one layer's), each layer is made in them."""
     n = jax.tree.leaves(shapes)[0].shape[0]
     one = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape[1:], s.dtype),
                        shapes)
-    return jax.lax.map(lambda k: _make_tree(k, one, sizes),
-                       jax.random.split(key, n))
+
+    def layer(k):
+        made = _make_tree(k, one, sizes, family)
+        if shardings is not None:
+            made = jax.lax.with_sharding_constraint(made, shardings)
+        return made
+    return jax.lax.map(layer, jax.random.split(key, n))
 
 
 def shapes_of(cfg) -> Dict[str, Any]:
@@ -83,24 +102,47 @@ def shapes_of(cfg) -> Dict[str, Any]:
                           jax.random.PRNGKey(0))
 
 
-def make_resident(cfg, sizes, seed: int):
-    """The whole tree on the device, made by one jitted call."""
+def plan_shardings(plan, shapes):
+    """The ``NamedSharding`` of each leaf of ``shapes`` under the program's
+    shard plan (``make_shard_plan(cfg, mesh, shapes)``)."""
+    from jax.sharding import NamedSharding, PartitionSpec
+    return jax.tree.map(lambda p: NamedSharding(plan.mesh, p),
+                        plan.param_specs_for(shapes),
+                        is_leaf=lambda x: isinstance(x, PartitionSpec))
+
+
+def resident_maker(cfg, sizes, *, plan=None, family=None):
+    """The jitted call that makes the whole tree from a key, on the device;
+    with the program's shard ``plan``, in its shardings on the plan's
+    mesh."""
     shapes = shapes_of(cfg)
     rest = {k: v for k, v in shapes.items() if k != "layers"}
+    out_shardings = one_layer = None
+    if plan is not None:
+        out_shardings = plan_shardings(plan, shapes)
+        one_layer = jax.tree.map(
+            lambda s: jax.sharding.NamedSharding(
+                plan.mesh, plan.layer_leaf_spec(s.spec)),
+            out_shardings["layers"])
 
-    @jax.jit
     def make(key):
         k1, k2 = jax.random.split(key)
-        out = _make_tree(k1, rest, sizes)
-        out["layers"] = _make_stacked(k2, shapes["layers"], sizes)
+        out = _make_tree(k1, rest, sizes, family)
+        out["layers"] = _make_stacked(k2, shapes["layers"], sizes, family,
+                                      one_layer)
         return out
+    return jax.jit(make, out_shardings=out_shardings)
 
-    params = make(_key(seed))
+
+def make_resident(cfg, sizes, seed: int, *, plan=None, family=None):
+    """The whole tree on the device, made by one jitted call
+    (``resident_maker``)."""
+    params = resident_maker(cfg, sizes, plan=plan, family=family)(_key(seed))
     jax.block_until_ready(params)
     return params
 
 
-def make_offload(cfg, sizes, seed: int):
+def make_offload(cfg, sizes, seed: int, *, family=None):
     """Layers in host memory (one stacked host array per leaf, each layer
     copied there as soon as it is made on the device); the rest of the tree
     on the device."""
@@ -109,10 +151,10 @@ def make_offload(cfg, sizes, seed: int):
     one = jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape[1:], s.dtype),
                        shapes["layers"])
     k_rest, k_layers = jax.random.split(_key(seed))
-    params = jax.jit(lambda k: _make_tree(k, rest, sizes))(k_rest)
+    params = jax.jit(lambda k: _make_tree(k, rest, sizes, family))(k_rest)
     host = jax.tree.map(lambda s: np.empty(s.shape, s.dtype),
                         shapes["layers"])
-    make_layer = jax.jit(lambda k: _make_tree(k, one, sizes))
+    make_layer = jax.jit(lambda k: _make_tree(k, one, sizes, family))
     layer_keys = jax.random.split(k_layers, jax.tree.leaves(host)[0].shape[0])
     for l in range(layer_keys.shape[0]):
         made = jax.device_get(make_layer(layer_keys[l]))

@@ -34,6 +34,16 @@ class Window:
         return self.cell.config
 
     @property
+    def chips(self) -> int:
+        """Chips the cell runs on; ``peaks`` are one chip's."""
+        return self.cell.chips
+
+    @property
+    def counts(self):
+        """The configuration's counts of FLOPs and bytes (``spec.Cell``)."""
+        return self.cell.counts
+
+    @property
     def slots(self) -> int:
         return int(self.cell.sizes["slots"])
 
